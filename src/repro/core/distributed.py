@@ -40,7 +40,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro import obs
 
@@ -223,10 +222,10 @@ def _shard_map_fn(scheme_name: str, cfg: DistConfig, probes, t_steps: int,
         return jax.tree.map(lambda x: x[None], out)
 
     return obs.InstrumentedJit(
-        jax.jit(shard_map(
+        jax.jit(jax.shard_map(
             sharded, mesh=mesh,
             in_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(AXIS), P()),
-            out_specs=P(AXIS), check_rep=False)),
+            out_specs=P(AXIS), check_vma=False)),
         f"distributed.shard_map.{scheme_name}")
 
 

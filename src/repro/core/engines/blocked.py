@@ -11,9 +11,11 @@ than synapse count".
 The tile store is built on host once per ``build`` (i.e. once per
 ``simulate()`` call, or once per benchmark when the caller reuses the
 state) and lives on device thereafter; the per-step ``deliver`` only
-moves the spike vector.  On TPU the kernel runs compiled (scalar-prefetch
-DMA gating); elsewhere it falls back to Pallas interpret mode so the
-engine stays testable on CPU.
+moves the spike vector.  ``build`` picks the mode from the backend: on a
+TPU the kernel runs compiled by Mosaic (scalar-prefetch DMA gating;
+``chip_smoke.py`` runs it on a v5e), on any other backend it runs in the
+Pallas interpreter, which is how the CPU tests exercise it — slowly, as
+the interpreter unrolls every stored tile.
 """
 
 from __future__ import annotations

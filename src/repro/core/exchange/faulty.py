@@ -8,9 +8,11 @@ survive, without hardware and inside CI:
 * **dropped payloads** (``drop_payload_at``): at configured steps the
   chosen partition's delayed spikes are zeroed *before* compaction — its
   whole outgoing fan-out silently vanishes from every partition's event
-  list.  Because the inner scheme's drop accounting compares requested
-  against kept fan-out, the loss shows up exactly in the ``dropped``
-  counter (a lost message is a counted message).
+  list.  Only the exchange sees the zeroed vector: the step body hands
+  ``deliver`` the partition's real delayed spikes, and the inner scheme's
+  drop accounting compares that requested fan-out against the kept one,
+  so the loss shows up exactly in the ``dropped`` counter (a lost message
+  is a counted message; tests/test_health.py pins the count).
 * **corrupt payloads** (``corrupt_payload_at``): the delayed-spike vector
   is rolled by one before compaction — wrong neuron ids enter the event
   list, the downstream signature of a corrupted routing table.

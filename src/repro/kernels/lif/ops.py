@@ -1,9 +1,11 @@
 """Public jit'd wrappers for the fused LIF kernel.
 
 Handles padding to the [rows, 128] kernel layout from flat [n] state and
-dispatches to the float32 or fixed-point kernel.  ``interpret=True`` (the
-default in this CPU container) runs the kernel body in the Pallas
-interpreter; on TPU pass ``interpret=False``.
+dispatches to the float32 or fixed-point kernel.  The simulator never
+calls this kernel (the fused delivery kernel calls
+:mod:`repro.core.neuron`'s step directly); only tests/test_kernels.py
+runs it, in the Pallas interpreter (``interpret=True``, the default).  It
+has not been compiled for a TPU.
 """
 
 from __future__ import annotations

@@ -20,14 +20,23 @@ insight as *block-level* event-driven delivery:
 Cost ∝ (number of live tiles) — the TPU-native rendering of "execution cost
 proportional to spiking activity rather than synapse count".
 
-BlockSpec geometry: weight tiles [1, TGT_BLK, SRC_BLK] stream through VMEM
-indexed by (tb, e); the spike vector is blocked [SRC_BLK] by the tile's
-source-block id via a scalar-prefetch index map.
+BlockSpec geometry: weight tiles [1, 1, TGT_BLK, SRC_BLK] stream through
+VMEM indexed by (tb, e); the spike vector is blocked [1, 1, SRC_BLK] by
+the tile's source-block id via a scalar-prefetch index map (``blk_id`` in
+SMEM).  Every per-neuron operand is laid out [rows, 1, 128] so a one-row
+block's last two dims equal the array's — the TPU compiler refuses a
+(1, 128) block over [rows, 128] — and the unfused kernel's per-block spike
+counts ride in SMEM as a second scalar-prefetch operand.
+
+Both kernels compile for the TPU (``interpret=False``, the engines' choice
+when ``jax.default_backend() == "tpu"``; tests/test_tpu_compile.py
+compiles them for a described v5e) and run in the Pallas interpreter
+everywhere else, which is how the CPU test suite exercises them.
 
 The fused variant (:func:`fused_deliver_lif_pallas`) goes one step
 further and closes the paper's whole per-timestep loop inside VMEM:
 after the last live tile of a target-row block has been accumulated, the
-same kernel invocation applies the :mod:`repro.kernels.lif` neuron body
+same kernel invocation applies the :mod:`repro.core.neuron` LIF step
 (int32 Q19.12 Loihi-faithful path or float32) to that block and emits
 the spike vector directly.  The delivered current lives only in a VMEM
 scratch accumulator — it never round-trips through HBM between delivery
@@ -55,24 +64,41 @@ TGT_BLK = 128
 SRC_BLK = 128
 
 
-def _deliver_body(blk_id_ref, spk_ref, w_ref, nspk_ref, out_ref):
+def _tile_matvec(w_ref, s):
+    """[1, SRC_BLK] spike row x the [TGT_BLK, SRC_BLK] tile -> [1, TGT_BLK]
+    drive row on the MXU.  HIGHEST precision keeps f32 weights exact on
+    the chip (integer weights and 0/1 spikes then sum exactly, as in the
+    XLA engines)."""
+    return jax.lax.dot_general(
+        s, w_ref[0, 0], (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
+def _deliver_body(blk_id_ref, nspk_ref, spk_ref, w_ref, out_ref):
     """grid = (n_tgt_blocks, E); accumulate gated tile matvecs."""
-    e = pl.program_id(1)
+    tb, e = pl.program_id(0), pl.program_id(1)
 
     @pl.when(e == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    live = nspk_ref[0] > 0
-
-    @pl.when(live)
+    # per-source-block spike count, read from SMEM by the tile's block id
+    @pl.when(nspk_ref[blk_id_ref[tb, e]] > 0)
     def _tile():
-        w = w_ref[0, 0]                   # [TGT_BLK, SRC_BLK] f32
-        s = spk_ref[...]                  # [1, SRC_BLK] f32 spike block
-        # MXU matvec as [TGT, SRC] @ [SRC, 1] -> transpose to the (1, TGT) row
-        out_ref[...] += jax.lax.dot_general(
-            w, s, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32).T
+        out_ref[0] += _tile_matvec(w_ref, spk_ref[0])
+
+
+# target blocks are independent; the E axis accumulates into the same
+# output block and must stay sequential
+_GRID_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"))
+
+
+def _rows3(x):
+    """[m, 128] -> [m, 1, 128]: each row is its own block whose last two
+    dims equal the array's, the layout Mosaic accepts for one-row blocks."""
+    return x.reshape(x.shape[0], 1, x.shape[1])
 
 
 def spike_deliver_pallas(blk_id, weights, spk_blocks, nspk_blocks,
@@ -87,36 +113,29 @@ def spike_deliver_pallas(blk_id, weights, spk_blocks, nspk_blocks,
     Returns: [n_tb, TGT_BLK] f32 accumulated drive.
     """
     n_tb, E = blk_id.shape
-    grid = (n_tb, E)
-    kwargs = {}
-    # class name varies across jax releases (TPUCompilerParams -> CompilerParams)
-    params_cls = getattr(pltpu, "TPUCompilerParams", None) or \
-        getattr(pltpu, "CompilerParams", None)
-    if not interpret and params_cls is not None:
-        # target blocks are independent; the E axis accumulates into the
-        # same output block and must stay sequential.
-        kwargs["compiler_params"] = params_cls(
-            dimension_semantics=("parallel", "arbitrary"))
-    # scalar-prefetch: the blk_id table is prefetched to SMEM and drives the
-    # spike-block / spike-count index maps (data-dependent DMA scheduling).
+    # scalar prefetch: blk_id and the spike counts go to SMEM; blk_id drives
+    # the spike-block index map (data-dependent DMA scheduling) and the
+    # counts gate each tile
     kernel = pl.pallas_call(
         _deliver_body,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
+            num_scalar_prefetch=2,
+            grid=(n_tb, E),
             in_specs=[
-                pl.BlockSpec((1, SRC_BLK), lambda tb, e, blk: (blk[tb, e], 0)),
+                pl.BlockSpec((1, 1, SRC_BLK),
+                             lambda tb, e, blk, nspk: (blk[tb, e], 0, 0)),
                 pl.BlockSpec((1, 1, TGT_BLK, SRC_BLK),
-                             lambda tb, e, blk: (tb, e, 0, 0)),
-                pl.BlockSpec((1,), lambda tb, e, blk: (blk[tb, e],)),
+                             lambda tb, e, blk, nspk: (tb, e, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, TGT_BLK), lambda tb, e, blk: (tb, 0)),
+            out_specs=pl.BlockSpec((1, 1, TGT_BLK),
+                                   lambda tb, e, blk, nspk: (tb, 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((n_tb, TGT_BLK), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_tb, 1, TGT_BLK), jnp.float32),
+        compiler_params=_GRID_PARAMS,
         interpret=interpret,
-        **kwargs,
     )
-    return kernel(blk_id, spk_blocks, weights, nspk_blocks)
+    out = kernel(blk_id, nspk_blocks, _rows3(spk_blocks), weights)
+    return out.reshape(n_tb, TGT_BLK)
 
 
 # --------------------------------------------------------------------------
@@ -139,15 +158,11 @@ def _accumulate_tile(spk_ref, w_ref, acc_ref):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    s = spk_ref[...]                      # [1, SRC_BLK] f32 spike block
-    live = jnp.any(s != 0.0)
+    s = spk_ref[0]                        # [1, SRC_BLK] f32 spike block
 
-    @pl.when(live)
+    @pl.when(jnp.max(s) > 0.0)
     def _tile():
-        w = w_ref[0, 0]                   # [TGT_BLK, SRC_BLK] f32
-        acc_ref[...] += jax.lax.dot_general(
-            w, s, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32).T
+        acc_ref[...] += _tile_matvec(w_ref, s)
 
 
 def _fused_body(blk_id_ref, spk_ref, w_ref, v_ref, g_ref, ref_ref, *rest,
@@ -174,10 +189,10 @@ def _fused_body(blk_id_ref, spk_ref, w_ref, v_ref, g_ref, ref_ref, *rest,
     def _integrate():
         g_units = acc_ref[...]
         if use_gstim:
-            g_units = g_units + gstim_ref[...]
-        lif = LIFState(v=v_ref[...], g=g_ref[...], refrac=ref_ref[...])
-        vin = vin_ref[...] if use_vin else None
-        force = (force_ref[...] != 0) if use_force else None
+            g_units = g_units + gstim_ref[0]
+        lif = LIFState(v=v_ref[0], g=g_ref[0], refrac=ref_ref[0])
+        vin = vin_ref[0] if use_vin else None
+        force = (force_ref[0] != 0) if use_force else None
         if fixed_point:
             # f32 accumulation -> integer weight units at the block
             # boundary, exactly apply_drive's conversion point
@@ -187,10 +202,10 @@ def _fused_body(blk_id_ref, spk_ref, w_ref, v_ref, g_ref, ref_ref, *rest,
         else:
             st, spikes = lif_step(lif, g_units * params.w_scale, params,
                                   vin, force)
-        v_out[...] = st.v
-        g_out[...] = st.g
-        refr_out[...] = st.refrac
-        spk_out[...] = spikes.astype(jnp.int32)
+        v_out[0] = st.v
+        g_out[0] = st.g
+        refr_out[0] = st.refrac
+        spk_out[0] = spikes.astype(jnp.int32)
 
 
 def fused_deliver_lif_pallas(blk_id, weights, spk_blocks, v, g, refrac,
@@ -214,41 +229,34 @@ def fused_deliver_lif_pallas(blk_id, weights, spk_blocks, v, g, refrac,
     Returns: (v, g, refrac, spikes) row blocks; spikes int32 0/1.
     """
     n_tb, E = blk_id.shape
-    grid = (n_tb, E)
     sdt = jnp.int32 if fixed_point else jnp.float32
     body = functools.partial(
         _fused_body, params=params, fixed_point=fixed_point,
         use_gstim=gstim is not None, use_vin=vin is not None,
         use_force=force is not None)
-    kwargs = {}
-    params_cls = getattr(pltpu, "TPUCompilerParams", None) or \
-        getattr(pltpu, "CompilerParams", None)
-    if not interpret and params_cls is not None:
-        kwargs["compiler_params"] = params_cls(
-            dimension_semantics=("parallel", "arbitrary"))
-    row = pl.BlockSpec((1, TGT_BLK), lambda tb, e, blk: (tb, 0))
-    stim_ops = [x for x in (gstim, vin, force) if x is not None]
+    row = pl.BlockSpec((1, 1, TGT_BLK), lambda tb, e, blk: (tb, 0, 0))
+    row_ops = [_rows3(x) for x in (v, g, refrac, gstim, vin, force)
+               if x is not None]
     kernel = pl.pallas_call(
         body,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
+            grid=(n_tb, E),
             in_specs=[
-                pl.BlockSpec((1, SRC_BLK), lambda tb, e, blk: (blk[tb, e], 0)),
+                pl.BlockSpec((1, 1, SRC_BLK),
+                             lambda tb, e, blk: (blk[tb, e], 0, 0)),
                 pl.BlockSpec((1, 1, TGT_BLK, SRC_BLK),
                              lambda tb, e, blk: (tb, e, 0, 0)),
-            ] + [row] * (3 + len(stim_ops)),
+            ] + [row] * len(row_ops),
             out_specs=[row, row, row, row],
             # the delivered current's only home: a VMEM scratch accumulator
             scratch_shapes=[pltpu.VMEM((1, TGT_BLK), jnp.float32)],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((n_tb, TGT_BLK), sdt),
-            jax.ShapeDtypeStruct((n_tb, TGT_BLK), sdt),
-            jax.ShapeDtypeStruct((n_tb, TGT_BLK), jnp.int32),
-            jax.ShapeDtypeStruct((n_tb, TGT_BLK), jnp.int32),
-        ],
+            jax.ShapeDtypeStruct((n_tb, 1, TGT_BLK), dt)
+            for dt in (sdt, sdt, jnp.int32, jnp.int32)],
+        compiler_params=_GRID_PARAMS,
         interpret=interpret,
-        **kwargs,
     )
-    return kernel(blk_id, spk_blocks, weights, v, g, refrac, *stim_ops)
+    outs = kernel(blk_id, _rows3(spk_blocks), weights, *row_ops)
+    return tuple(x.reshape(n_tb, TGT_BLK) for x in outs)

@@ -23,7 +23,6 @@ import jax             # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np     # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
-from jax.experimental.shard_map import shard_map            # noqa: E402
 
 from repro.configs.flywire import CONFIG, SMOKE             # noqa: E402
 from repro.core import (CoreBudget, caps_from_budget,       # noqa: E402
@@ -132,8 +131,9 @@ def main():
     spec_c = jax.tree.map(lambda _: P("cores"), carry)
     spec_a = jax.tree.map(lambda _: P("cores"), arrs)
     spec_s = jax.tree.map(lambda _: P("cores"), stim)
-    fn = shard_map(run_window, mesh=mesh, in_specs=(spec_c, spec_a, spec_s),
-                   out_specs=spec_c, check_rep=False)
+    fn = jax.shard_map(run_window, mesh=mesh,
+                       in_specs=(spec_c, spec_a, spec_s), out_specs=spec_c,
+                       check_vma=False)
     sh_c = jax.tree.map(lambda s: NamedSharding(mesh, s), spec_c)
     sh_a = jax.tree.map(lambda s: NamedSharding(mesh, s), spec_a)
     sh_s = jax.tree.map(lambda s: NamedSharding(mesh, s), spec_s)

@@ -10,18 +10,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-try:  # AxisType landed after jax 0.4; older versions imply Auto everywhere
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - version-dependent
-    AxisType = None
-
-
-def _axis_kw(n_axes: int) -> dict:
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -29,7 +18,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     pure-DP "pod" axis (2, 16, 16) = 512 chips across the DCN boundary."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kw(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 2, model: int = 2, pod: int | None = None):
@@ -41,10 +31,11 @@ def make_host_mesh(data: int = 2, model: int = 2, pod: int | None = None):
     n = int(np.prod(shape))
     if len(jax.devices()) < n:
         raise ValueError(f"need {n} devices, have {len(jax.devices())}")
-    return jax.make_mesh(shape, axes, **_axis_kw(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_flat_mesh(n_cores: int, name: str = "cores"):
     """1-D mesh used by the distributed SNN simulator (one neuron partition
     per device)."""
-    return jax.make_mesh((n_cores,), (name,), **_axis_kw(1))
+    return jax.make_mesh((n_cores,), (name,), axis_types=(AxisType.Auto,))

@@ -27,6 +27,7 @@ from repro.core import SimConfig, synthetic_flywire_cached
 from repro.core.exchange import FaultSpec, configure_faulty
 from repro.core.health import BackoffPolicy, HealthConfig
 from repro.exp import ProbeSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import TERMINAL, SimRequest, SimServeConfig, SimServer
 
 
@@ -55,6 +56,7 @@ def build_workload(requests: int, t_steps: int, inject_fault: bool,
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=400)
     ap.add_argument("--synapses", type=int, default=8_000)

@@ -34,9 +34,11 @@ from repro.core.dcsr import build_dcsr
 from repro.core.distributed import DistConfig, simulate_distributed
 from repro.exp import (available_scenarios, build_scenario, get_scenario,
                        run_dist_trials, run_trials)
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", choices=["smoke", "bench", "full"],
                     default="bench")

@@ -68,7 +68,6 @@ def pipeline_apply(stage_fn, stage_params, xs, mesh, n_stages: int,
                    axis: str = "stage"):
     """shard_map pipeline on a real mesh with a `stage` axis."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     def shard_fn(params, xs_all):
         params = jax.tree.map(lambda a: a[0], params)
@@ -80,6 +79,6 @@ def pipeline_apply(stage_fn, stage_params, xs, mesh, n_stages: int,
         return jax.lax.psum(outs, axis)
 
     spec_p = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = shard_map(shard_fn, mesh=mesh, in_specs=(spec_p, P()),
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=(spec_p, P()),
+                       out_specs=P(), check_vma=False)
     return jax.jit(fn)(stage_params, xs)

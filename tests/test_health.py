@@ -315,17 +315,29 @@ def test_faulty_failure_exceeds_restarts(setup, tmp_path):
 
 def test_faulty_payload_drop_is_counted(setup):
     """A lost payload is a counted loss: the failed partition's whole
-    outgoing fan-out lands in the exact ``dropped`` counter."""
+    outgoing fan-out lands in the exact ``dropped`` counter — at every
+    faulty step, the global fan-out of each spike partition 0 sent."""
     c, sugar, d = setup
     clean = DistConfig(sim=SimConfig(engine="event"), scheme="event")
     ref = _run_dist(d, clean, 60, sugar)
+    fault_steps = range(20, 50)
     configure_faulty(inner="event",
                      spec=FaultSpec(partition=0,
-                                    drop_payload_at=tuple(range(20, 50))))
+                                    drop_payload_at=tuple(fault_steps)))
     fcfg = DistConfig(sim=SimConfig(engine="event"), scheme="faulty")
     out = _run_dist(d, fcfg, 60, sugar)
-    assert int(out.dropped) > int(ref.dropped)
-    assert not np.array_equal(out.counts, ref.counts)
+    configure_faulty()   # reset to clean defaults for other tests
+    assert int(ref.dropped) == 0              # the clean run is lossless
+    # step t exchanges the spikes of step t - delay; partition 0's sources
+    # in original ids, each costing its whole fan-out
+    delay = SimConfig().params.delay_steps
+    on_p0 = d.perm // d.part_size == 0
+    fan_out = np.diff(c.out_indptr)
+    raster = np.asarray(out.raster)
+    lost = sum(int((raster[t - delay] & on_p0) @ fan_out)
+               for t in fault_steps if t >= delay)
+    assert lost > 0
+    assert int(out.dropped) == lost
 
 
 def test_faulty_configure_guards():
