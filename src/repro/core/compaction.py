@@ -39,7 +39,10 @@ O(S_cap · log K) gather-heavy probe per slot.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+
+from repro.obs import scopes
 
 BLOCK = 128   # compaction granularity; matches the blocked engine's tile
 
@@ -57,6 +60,7 @@ def derived_block_capacity(n: int, spike_capacity: int,
     return max(1, min(n_blocks(n, block), spike_capacity))
 
 
+@jax.named_scope(scopes.COMPACTION)
 def two_level_active(spikes: jnp.ndarray, spike_capacity: int,
                      block_capacity: int, block: int = BLOCK) -> jnp.ndarray:
     """Compact spiking indices into ``[spike_capacity]`` int32, ascending,
@@ -96,6 +100,7 @@ def slot_owner(seg_end: jnp.ndarray, syn_budget: int) -> jnp.ndarray:
     return jnp.cumsum(bump[:syn_budget])
 
 
+@jax.named_scope(scopes.SLOT_OWNER)
 def ragged_slots(ids: jnp.ndarray, indptr: jnp.ndarray, syn_budget: int, *,
                  invalid_from: int, gather_size: int):
     """Assign the fan-out synapse runs of compacted ``ids`` to a bounded
@@ -129,6 +134,7 @@ def ragged_slots(ids: jnp.ndarray, indptr: jnp.ndarray, syn_budget: int, *,
     return syn_ix, ok, total
 
 
+@jax.named_scope(scopes.DROP_COUNT)
 def active_fanout_total(spikes: jnp.ndarray, indptr: jnp.ndarray):
     """Exact number of synapses the spike vector *requests* — the
     drop-accounting ground truth (requested - delivered = dropped), immune

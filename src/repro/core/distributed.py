@@ -255,25 +255,27 @@ def _run_partitioned(d: DCSR, cfg: DistConfig, t_steps: int, keys,
     stim = _resolve_dist_stimulus(d, cfg.sim, sugar_neurons, stimulus)
     probes, vrows, owner = _resolve_dist_probes(d, cfg.sim, probes)
     pad = jnp.asarray(d.inv_perm.reshape(d.n_parts, d.part_size) >= 0)
-    carry0 = _init_dist_carry(d, cfg, stim, scheme, keys)
+    with obs.span("init_carry"):
+        carry0 = _init_dist_carry(d, cfg, stim, scheme, keys)
     if not emulate and mesh is None:
         mesh = make_core_mesh(d.n_parts)
 
     def run(carry, k, t0):
-        if emulate:
-            return _run_emulated(cfg.scheme, carry, state, stim, pad, vrows,
-                                 cfg, probes, k, trials, t0)
-        return _run_shard_map(cfg.scheme, carry, state, stim, pad, vrows,
-                              cfg, probes, k, trials, mesh, t0)
+        with obs.span("dispatch"):
+            if emulate:
+                return _run_emulated(cfg.scheme, carry, state, stim, pad,
+                                     vrows, cfg, probes, k, trials, t0)
+            return _run_shard_map(cfg.scheme, carry, state, stim, pad, vrows,
+                                  cfg, probes, k, trials, mesh, t0)
 
     # a telemetry session routes single runs through the chunk driver
     # (one chunk when chunk_steps is None) for the per-chunk event
-    # stream; the trial-batched path stays unsupervised (spans and
-    # compile metrics still apply)
-    supervised = (chunk_steps is not None or checkpoint_dir is not None
-                  or cfg.sim.health is not None
-                  or (obs.active() is not None and not trials))
-    if not supervised:
+    # stream, running the plain call's program (no step offset); the
+    # trial-batched path stays unsupervised (spans and compile metrics
+    # still apply)
+    single = (chunk_steps is None and checkpoint_dir is None
+              and cfg.sim.health is None)
+    if single and (trials or obs.active() is None):
         out, records = run(carry0, t_steps, None)
     else:
         if trials:
@@ -284,7 +286,7 @@ def _run_partitioned(d: DCSR, cfg: DistConfig, t_steps: int, keys,
         ckpt = (SimCheckpointer(checkpoint_dir, async_save=async_checkpoint)
                 if checkpoint_dir is not None else None)
         out, records = run_chunked(
-            lambda cy, s, k: run(cy, k, jnp.int32(s)),
+            lambda cy, s, k: run(cy, k, None if single else jnp.int32(s)),
             carry0, t_steps, chunk_steps,
             time_axis=1,            # records are partition-stacked [P, K, ..]
             health=cfg.sim.health, n=d.n_orig, dt_ms=cfg.sim.params.dt,

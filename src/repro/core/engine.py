@@ -231,27 +231,35 @@ def simulate(
             syn = build_synapses(c, cfg)
         stimulus = _resolve_stimulus(cfg, n, sugar_neurons, stimulus)
         probes = _resolve_probes(cfg, probes)
-        carry = _init_carry(n, cfg, stimulus, seed)
+        with obs.span("init_carry"):
+            carry = _init_carry(n, cfg, stimulus, seed)
         if tele is not None:
             tele.emit("run_start", kind="simulate", engine=cfg.engine,
                       n=n, t_steps=t_steps, chunk_steps=chunk_steps,
                       fixed_point=cfg.fixed_point)
         t_run = time.monotonic()
+        single = (chunk_steps is None and checkpoint_dir is None
+                  and cfg.health is None)
         # telemetry routes through the supervised chunk driver (one chunk
         # when chunk_steps is None) so the per-chunk event stream exists;
         # the chunked scan is bit-identical to the monolithic one
-        if (chunk_steps is None and checkpoint_dir is None
-                and cfg.health is None and tele is None):
-            carry, records = _run_scan(syn, carry, stimulus, cfg, probes,
-                                       t_steps, n)
+        if single and tele is None:
+            with obs.span("dispatch"):
+                carry, records = _run_scan(syn, carry, stimulus, cfg, probes,
+                                           t_steps, n)
         else:
             ckpt = (SimCheckpointer(checkpoint_dir,
                                     async_save=async_checkpoint)
                     if checkpoint_dir is not None else None)
 
             def run_chunk(cy, s, k):
-                return _run_scan(syn, cy, stimulus, cfg, probes, k, n,
-                                 jnp.int32(s))
+                # the one chunk of an unsupervised run is the plain call's
+                # program (no step offset), so telemetry observes the
+                # program that runs without it
+                t0 = () if single else (jnp.int32(s),)
+                with obs.span("dispatch"):
+                    return _run_scan(syn, cy, stimulus, cfg, probes, k, n,
+                                     *t0)
 
             carry, records = run_chunked(
                 run_chunk, carry, t_steps, chunk_steps, time_axis=0,

@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import scopes
+
 from ..capacity import CapacityConfig
 from ..compaction import (BLOCK, active_fanout_total, derived_block_capacity,
                           n_blocks, ragged_slots, slot_owner,
@@ -130,9 +132,13 @@ class EventEngine:
         syn_ix, ok, total = ragged_slots(
             act_idx, state.out_indptr, cap.syn_budget,
             invalid_from=n, gather_size=state.out_tgt.shape[0])
-        contrib = jnp.where(ok, state.out_w[syn_ix], 0.0)
-        tgt = jnp.where(ok, state.out_tgt[syn_ix], n)
-        g = jax.ops.segment_sum(contrib, tgt, num_segments=n + 1)[:n]
+        with jax.named_scope(scopes.SYN_GATHER):
+            contrib = jnp.where(ok, state.out_w[syn_ix], 0.0)
+            tgt = jnp.where(ok, state.out_tgt[syn_ix], n)
+        with jax.named_scope(scopes.SYN_SCATTER):
+            g = jax.ops.segment_sum(contrib, tgt, num_segments=n + 1)[:n]
         requested = active_fanout_total(spikes, state.out_indptr)
-        delivered = jnp.minimum(total, cap.syn_budget)
-        return g, requested - delivered
+        with jax.named_scope(scopes.DROP_COUNT):
+            delivered = jnp.minimum(total, cap.syn_budget)
+            dropped = requested - delivered
+        return g, dropped

@@ -16,6 +16,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.obs import scopes
+
 from ..compaction import derived_block_capacity, ragged_slots, two_level_active
 from .arrays import build_dist_arrays
 from .base import Topology, register_scheme
@@ -38,6 +40,7 @@ def gather_active_events(delayed: jax.Array, cap, topo: Topology):
     return events, idx
 
 
+@jax.named_scope(scopes.DROP_COUNT)
 def capacity_overflow_fanout(delayed, idx, src_gfo, U: int):
     """Global fan-out of the spikes the bounded compaction could not keep —
     they never enter any partition's event list, so their whole fan-out is
@@ -57,10 +60,14 @@ def deliver_events(events: jax.Array, out_indptr, out_tgt, out_w,
     syn_ix, ok, total = ragged_slots(
         events, out_indptr, syn_budget,
         invalid_from=n_glob, gather_size=out_tgt.shape[0])
-    contrib = jnp.where(ok, out_w[syn_ix], 0.0)
-    tgt = jnp.where(ok, out_tgt[syn_ix], U)
-    g = jax.ops.segment_sum(contrib, tgt, num_segments=U + 1)[:U]
-    return g, jnp.maximum(total - syn_budget, 0)
+    with jax.named_scope(scopes.SYN_GATHER):
+        contrib = jnp.where(ok, out_w[syn_ix], 0.0)
+        tgt = jnp.where(ok, out_tgt[syn_ix], U)
+    with jax.named_scope(scopes.SYN_SCATTER):
+        g = jax.ops.segment_sum(contrib, tgt, num_segments=U + 1)[:U]
+    with jax.named_scope(scopes.DROP_COUNT):
+        dropped = jnp.maximum(total - syn_budget, 0)
+    return g, dropped
 
 
 @register_scheme
@@ -81,6 +88,7 @@ class EventExchange:
         U, n_glob = topo.part_size, topo.n_global
         g, drop = deliver_events(events, state.out_indptr, state.out_tgt,
                                  state.out_w, U, n_glob, cap.syn_budget)
-        drop = drop.astype(jnp.int32) + capacity_overflow_fanout(
-            delayed, idx, state.src_gfo, U)
+        with jax.named_scope(scopes.DROP_COUNT):
+            drop = drop.astype(jnp.int32) + capacity_overflow_fanout(
+                delayed, idx, state.src_gfo, U)
         return g, drop, {}

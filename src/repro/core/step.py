@@ -28,6 +28,8 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs import scopes
+
 from .exchange.base import ExchangeScheme, Topology
 from .health import health_step_stats
 from .neuron import LIFState
@@ -63,46 +65,59 @@ def sim_step(carry: SimCarry, t, *, scheme: ExchangeScheme, state, stim,
     real neurons) keeps padding slots inert on padded partitions;
     ``voltage_rows`` optionally remaps the probe's voltage ids onto this
     partition's local rows (see :meth:`repro.exp.ProbeSpec.collect`).
+    Each layer of the body runs under its :mod:`repro.obs.scopes` name,
+    so a profiler trace splits the step's device time by layer.
     """
     from repro.exp.stimulus import apply_drive, n_split
     p = sim.params
-    keys = jax.random.split(carry.key, n_split(stim))
-    delayed = carry.ring[carry.ptr]
+    with jax.named_scope(scopes.STIMULUS):
+        keys = jax.random.split(carry.key, n_split(stim))
+    with jax.named_scope(scopes.RING):
+        delayed = carry.ring[carry.ptr]
 
     # Optional step-aware exchange (the fault-injection wrapper needs the
     # step index to corrupt/drop payloads at configured steps); ordinary
     # schemes keep the t-free protocol method.
     ex_at = getattr(scheme, "exchange_at", None)
-    payload = (scheme.exchange(state, delayed, cap, topo) if ex_at is None
-               else ex_at(state, delayed, cap, topo, t))
-    sstate, drive = stim.step(carry.stim, keys[1:], t, topo.part_size, p)
+    with jax.named_scope(scopes.EXCHANGE):
+        payload = (scheme.exchange(state, delayed, cap, topo) if ex_at is None
+                   else ex_at(state, delayed, cap, topo, t))
+    with jax.named_scope(scopes.STIMULUS):
+        sstate, drive = stim.step(carry.stim, keys[1:], t, topo.part_size, p)
     if _scheme_fuses_lif(scheme, sim):
         # fused fast path: the engine already integrated (delivery + LIF
         # in one kernel) — running apply_drive here would double-integrate
-        lif, spikes, drop, stats = scheme.deliver_fused(
-            state, payload, delayed, carry.lif, drive, sim, cap, topo)
+        with jax.named_scope(scopes.DELIVERY):
+            lif, spikes, drop, stats = scheme.deliver_fused(
+                state, payload, delayed, carry.lif, drive, sim, cap, topo)
     else:
-        g_units, drop, stats = scheme.deliver(state, payload, delayed, sim,
-                                              cap, topo)
-        lif, spikes = apply_drive(carry.lif, g_units, drive, p,
-                                  sim.fixed_point)
+        with jax.named_scope(scopes.DELIVERY):
+            g_units, drop, stats = scheme.deliver(state, payload, delayed,
+                                                  sim, cap, topo)
+        with jax.named_scope(scopes.LIF):
+            lif, spikes = apply_drive(carry.lif, g_units, drive, p,
+                                      sim.fixed_point)
     if pad_mask is not None:
-        spikes = jnp.logical_and(spikes, pad_mask)
+        with jax.named_scope(scopes.LIF):
+            spikes = jnp.logical_and(spikes, pad_mask)
 
-    ring = carry.ring.at[carry.ptr].set(spikes)
-    ptr = (carry.ptr + 1) % p.delay_steps
-    # health sentinels (repro.core.health) accumulate next to the scheme
-    # counters; both dicts are keyed disjointly and the carry's stats
-    # structure is the static union fixed at init time
-    stats = {**stats, **health_step_stats(lif, sim)}
-    new = SimCarry(
-        lif=lif, ring=ring, ptr=ptr, key=keys[0],
-        counts=carry.counts + spikes.astype(jnp.int32),
-        dropped=carry.dropped + drop.astype(jnp.int32),
-        stim=sstate,
-        stats={k: carry.stats[k] + stats[k] for k in carry.stats})
-    return new, probes.collect(spikes=spikes, lif=lif, drop=drop, params=p,
-                               voltage_rows=voltage_rows)
+    with jax.named_scope(scopes.RING):
+        ring = carry.ring.at[carry.ptr].set(spikes)
+        ptr = (carry.ptr + 1) % p.delay_steps
+        # health sentinels (repro.core.health) accumulate next to the
+        # scheme counters; both dicts are keyed disjointly and the carry's
+        # stats structure is the static union fixed at init time
+        stats = {**stats, **health_step_stats(lif, sim)}
+        new = SimCarry(
+            lif=lif, ring=ring, ptr=ptr, key=keys[0],
+            counts=carry.counts + spikes.astype(jnp.int32),
+            dropped=carry.dropped + drop.astype(jnp.int32),
+            stim=sstate,
+            stats={k: carry.stats[k] + stats[k] for k in carry.stats})
+    with jax.named_scope(scopes.PROBES):
+        records = probes.collect(spikes=spikes, lif=lif, drop=drop, params=p,
+                                 voltage_rows=voltage_rows)
+    return new, records
 
 
 def scan_steps(scheme: ExchangeScheme, state, carry: SimCarry, stim, sim,

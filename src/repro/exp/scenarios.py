@@ -32,6 +32,8 @@ from typing import Any, Callable
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 from .stimulus import (Background, Compose, PoissonDrive, PulseTrain,
                        RampDrive, SILENT, StepCurrent, per_neuron)
 
@@ -79,7 +81,8 @@ def build_scenario(name: str, c, cfg, **overrides):
     if unknown:
         raise ValueError(f"scenario {name!r} has no params {sorted(unknown)}; "
                          f"accepts {sorted(s.defaults)}")
-    return s.build(c, cfg, **{**s.defaults, **overrides})
+    with obs.span("scenario", scenario=name):
+        return s.build(c, cfg, **{**s.defaults, **overrides})
 
 
 def _pick(c, n_targets: int, seed: int) -> np.ndarray:

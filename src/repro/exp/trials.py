@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.connectome import Connectome
 from repro.core.engine import (SimConfig, _init_carry, _resolve_probes,
                                _resolve_stimulus, _run_scan_trials,
@@ -94,25 +95,29 @@ def run_trials(
     counters summed over the whole batch (per-lane attribution is the
     serving layer's job — :mod:`repro.serving.sim`).
     """
-    n = c.n
-    if syn is None:
-        syn = build_synapses(c, cfg)
-    stimulus = _resolve_stimulus(cfg, n, sugar_neurons, stimulus)
-    probes = _resolve_probes(cfg, probes)
-    carry, seeds = trial_carry(n, cfg, stimulus, seeds)
+    with obs.span("run_trials", engine=cfg.engine):
+        n = c.n
+        if syn is None:
+            syn = build_synapses(c, cfg)
+        stimulus = _resolve_stimulus(cfg, n, sugar_neurons, stimulus)
+        probes = _resolve_probes(cfg, probes)
+        with obs.span("init_carry"):
+            carry, seeds = trial_carry(n, cfg, stimulus, seeds)
 
-    if chunk_steps:
-        def run_chunk(cy, s, k):
-            return _run_scan_trials(syn, cy, stimulus, cfg, probes, k, n,
-                                    jnp.int32(s))
-        # records are [B, T, ...] on the batched path -> time axis 1; the
-        # rate envelope normalizes by the batch-summed neuron count
-        carry, records = run_chunked(
-            run_chunk, carry, t_steps, chunk_steps, time_axis=1,
-            health=cfg.health, n=n * len(seeds), dt_ms=cfg.params.dt)
-    else:
-        carry, records = _run_scan_trials(syn, carry, stimulus, cfg, probes,
-                                          t_steps, n)
+        if chunk_steps:
+            def run_chunk(cy, s, k):
+                with obs.span("dispatch"):
+                    return _run_scan_trials(syn, cy, stimulus, cfg, probes,
+                                            k, n, jnp.int32(s))
+            # records are [B, T, ...] on the batched path -> time axis 1;
+            # the rate envelope normalizes by the batch-summed neuron count
+            carry, records = run_chunked(
+                run_chunk, carry, t_steps, chunk_steps, time_axis=1,
+                health=cfg.health, n=n * len(seeds), dt_ms=cfg.params.dt)
+        else:
+            with obs.span("dispatch"):
+                carry, records = _run_scan_trials(syn, carry, stimulus, cfg,
+                                                  probes, t_steps, n)
     return TrialResult(counts=carry.counts, dropped=carry.dropped,
                        state=carry.lif, records=records, seeds=seeds)
 

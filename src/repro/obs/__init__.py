@@ -19,8 +19,12 @@ Pieces:
   :class:`MetricsRegistry`.
 * :class:`span` — nested, thread-safe, monotonic-clock tracing spans
   (``span("build")`` / ``span("compile")`` / ``span("chunk")``) wired
-  through ``simulate``, ``simulate_distributed``, ``run_resilient`` and
-  the host-side builds.
+  through ``simulate``, ``simulate_distributed``, ``run_trials``,
+  ``run_resilient`` and the host-side builds; each also writes a
+  ``repro.<name>`` annotation into a running ``jax.profiler`` trace.
+* :mod:`repro.obs.scopes` — the names of the step's layers on the
+  device (``jax.named_scope`` in the step body), so a profiler trace
+  splits device time by layer.
 * :class:`InstrumentedJit` — per-signature compile-cache metrics
   (hit/miss counters, trace+compile wall time, ``cost_analysis()``
   FLOPs/bytes) around the jitted scan entry points.

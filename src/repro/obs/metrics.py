@@ -88,10 +88,5 @@ class MetricsRegistry:
                 "signatures": [dict(r) for r in self._compiles],
             }
 
-    def snapshot(self) -> dict:
-        return {"counters": self.counters(),
-                "observations": self.observations(),
-                "compile_cache": self.compile_snapshot()}
-
 
 __all__ = ["MetricsRegistry"]

@@ -4,13 +4,15 @@ A session (:func:`telemetry` context manager) carries an optional
 :class:`~repro.obs.events.EventSink` and a
 :class:`~repro.obs.metrics.MetricsRegistry`.  Instrumented code asks
 :func:`active` once and no-ops when there is no session — the whole
-layer costs nothing (and adds zero device operations) unless the caller
-opted in.
+layer costs one host annotation per span (and adds zero device
+operations) unless the caller opted in.
 
 :class:`span` is the phase-tracing primitive: monotonic clock, nesting
 (per-thread depth stacks, so concurrently supervised runs never corrupt
 each other), and on exit one ``span`` event plus a
-``phase.<name>`` observation in the registry.
+``phase.<name>`` observation in the registry.  Every span, with or
+without a session, also lands in a running ``jax.profiler`` trace as the
+host annotation ``repro.<name>``.
 """
 
 from __future__ import annotations
@@ -101,12 +103,17 @@ def telemetry(sink=None, *, metrics: Optional[MetricsRegistry] = None,
 class span:
     """Tracing span: ``with span("build", what="synapses"): ...``.
 
-    No-op (two attribute checks, no clock read) without an active
-    session.  On exit: ``wall_s`` is set on the span object, a ``span``
-    event is emitted, and ``phase.<name>`` is observed in the registry.
+    Always opens a ``jax.profiler.TraceAnnotation`` named
+    ``repro.<name>``, so a profiler trace shows the host's work on the
+    same clock as the device's operations (the annotation costs about a
+    microsecond when no profiler runs).  Without an active session that
+    is all it does: no clock read, no event.  With one, on exit:
+    ``wall_s`` is set on the span object, a ``span`` event is emitted,
+    and ``phase.<name>`` is observed in the registry.
     """
 
-    __slots__ = ("name", "attrs", "wall_s", "_session", "_start", "_depth")
+    __slots__ = ("name", "attrs", "wall_s", "_session", "_start", "_depth",
+                 "_annotation")
 
     def __init__(self, name: str, **attrs):
         self.name = name
@@ -115,6 +122,9 @@ class span:
         self._session: Optional[Telemetry] = None
 
     def __enter__(self):
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation(f"repro.{self.name}")
+        self._annotation.__enter__()
         s = active()
         if s is not None:
             self._session = s
@@ -135,6 +145,7 @@ class span:
             if self.attrs:
                 fields["attrs"] = self.attrs
             s.emit("span", **fields)
+        self._annotation.__exit__(exc_type, exc, tb)
         return False
 
 

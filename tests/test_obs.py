@@ -98,6 +98,63 @@ def test_telemetry_bit_identity_distributed(setup):
     _assert_bitwise(ref, tele)
 
 
+def _spy(monkeypatch, module, attr):
+    """Record the compile-cache signature of every call to the
+    ``InstrumentedJit`` at ``module.attr``."""
+    real = getattr(module, attr)
+    seen = []
+
+    def spy(*args):
+        seen.append(real._sig_id(real._signature(args)))
+        return real(*args)
+
+    monkeypatch.setattr(module, attr, spy)
+    return seen
+
+
+def _session_signatures(tele, fn):
+    return [r["signature"] for r in tele.metrics.compile_snapshot()[
+        "signatures"] if r["fn"] == fn]
+
+
+def test_session_runs_the_plain_program(setup, monkeypatch):
+    """One unchunked simulate() under a session compiles and runs the very
+    program the session-less call runs (no step offset), still as one
+    chunk of the event stream."""
+    from repro.core import engine
+    c, sugar, _ = setup
+    cfg = SimConfig(engine="event", fixed_point=True)
+    seen = _spy(monkeypatch, engine, "_run_scan")
+    # 47 steps: a signature no other session of this module compiled
+    ref = _run(c, cfg, 47, sugar)
+    events = []
+    with obs.telemetry(events.append, validate=True) as tele:
+        got = _run(c, cfg, 47, sugar)
+        sigs = _session_signatures(tele, "engine.run_scan")
+    assert len(seen) == 2 and seen[0] == seen[1]
+    assert sigs == [seen[0]]
+    assert [e["steps"] for e in events if e["type"] == "chunk"] == [47]
+    _assert_bitwise(ref, got)
+
+
+def test_session_runs_the_plain_program_distributed(setup, monkeypatch):
+    from repro.core import distributed
+    c, sugar, d = setup
+    dcfg = DistConfig(sim=SimConfig(engine="event"), scheme="event")
+    seen = _spy(monkeypatch, distributed, "_run_emulated")
+    ref = _run_dist(d, dcfg, 46, sugar)
+    events = []
+    with obs.telemetry(events.append, validate=True) as tele:
+        got = _run_dist(d, dcfg, 46, sugar)
+        sigs = _session_signatures(tele, "distributed.run_emulated")
+        _run_dist(d, dcfg, 46, sugar, chunk_steps=23)   # offset program
+    assert len(seen) == 4 and seen[0] == seen[1] != seen[2] == seen[3]
+    assert sigs == [seen[0]]
+    assert [e["steps"] for e in events if e["type"] == "chunk"] == [46, 23,
+                                                                    23]
+    _assert_bitwise(ref, got)
+
+
 def test_compile_cache_in_stats_only_with_session(setup):
     c, sugar, _ = setup
     cfg = SimConfig(engine="csr")
@@ -231,6 +288,49 @@ def test_span_noop_without_session():
         pass
     assert sp.wall_s is None
     assert obs.active() is None
+
+
+def _host_annotations(trace_dir) -> set:
+    from glob import glob
+
+    from jax.profiler import ProfileData
+    [path] = glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    pd = ProfileData.from_file(path)
+    return {e.name for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+
+
+def test_span_writes_profiler_annotation(tmp_path):
+    """Every span lands in a running profiler trace as ``repro.<name>``,
+    with a telemetry session and without one."""
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.span("alone") as sp:
+            pass
+        with obs.telemetry():
+            with obs.span("in_session"):
+                pass
+    assert sp.wall_s is None
+    assert {"repro.alone", "repro.in_session"} <= _host_annotations(tmp_path)
+
+
+def test_hot_path_spans_in_profiler_trace(setup, tmp_path):
+    """The host's work on the hot path is named in the trace: the
+    scenario draw, the carry init and the dispatch of the scan, for single
+    runs, trial batches and partitioned runs."""
+    from repro.exp import build_scenario, run_trials
+    c, sugar, d = setup
+    cfg = SimConfig(engine="event")
+    dcfg = DistConfig(sim=cfg, scheme="event")
+    with jax.profiler.trace(str(tmp_path)):
+        stim = build_scenario("sugar_feeding", c, cfg, n_sugar=20)
+        jax.block_until_ready(simulate(c, cfg, 10, stimulus=stim).counts)
+        jax.block_until_ready(run_trials(c, cfg, 10, seeds=2,
+                                         stimulus=stim).counts)
+        _run_dist(d, dcfg, 10, sugar)
+    names = _host_annotations(tmp_path)
+    assert {"repro.scenario", "repro.simulate", "repro.init_carry",
+            "repro.dispatch", "repro.run_trials",
+            "repro.simulate_distributed"} <= names
 
 
 def test_span_nesting_depth_and_metrics():
