@@ -33,8 +33,20 @@ Slot->owner assignment
 ``owner[s] = #{k : seg_end[k] <= s}`` equals
 ``searchsorted(seg_end, slot, side="right")`` but is computed by scattering
 a unit bump at each segment end and taking an inclusive cumsum over the
-budget — O(S_cap + K) sequential-friendly work instead of the
-O(S_cap · log K) gather-heavy probe per slot.
+slot window — O(C + K) sequential-friendly work instead of the
+O(C · log K) gather-heavy probe per slot.
+
+Activity-bounded slot loop
+--------------------------
+The slot budget ``S_cap`` is a static shape, but a step only has
+``min(total, S_cap)`` synapses to deliver.  :func:`deliver_ragged` cuts
+the budget into chunks of :func:`slot_chunk` slots and runs the
+owner -> gather -> scatter work one chunk at a time in a
+``lax.while_loop`` whose trip count, ``ceil(min(total, S_cap) / C)``, is
+computed on the device: a step with no delayed spikes runs no trip, and
+slot work is never more than ``min(total, S_cap) + C``.  The chunks visit
+the slots in the same ascending order as one full-budget pass, so the
+delivered set, and the drops, are unchanged.
 """
 
 from __future__ import annotations
@@ -45,6 +57,8 @@ import jax.numpy as jnp
 from repro.obs import scopes
 
 BLOCK = 128   # compaction granularity; matches the blocked engine's tile
+#: most chunks the slot loop cuts a synapse budget into (see slot_chunk)
+SLOT_CHUNKS = 16
 
 
 def n_blocks(n: int, block: int = BLOCK) -> int:
@@ -90,48 +104,114 @@ def two_level_active(spikes: jnp.ndarray, spike_capacity: int,
     return jnp.where(lvalid, gid, n).astype(jnp.int32)
 
 
-def slot_owner(seg_end: jnp.ndarray, syn_budget: int) -> jnp.ndarray:
-    """owner[s] = #{k : seg_end[k] <= s} for s in [0, syn_budget) — equal to
-    ``searchsorted(seg_end, slot, side="right")`` but computed by scattering
-    a unit bump at each segment end and taking an inclusive cumsum:
-    O(S_cap + K) instead of O(S_cap · log K)."""
-    bump = jnp.zeros(syn_budget + 1, jnp.int32).at[
-        jnp.minimum(seg_end, syn_budget)].add(1)
-    return jnp.cumsum(bump[:syn_budget])
+def slot_chunk(syn_budget: int) -> int:
+    """Slots per trip of the slot loop: whole 128-lane rows, at most
+    :data:`SLOT_CHUNKS` trips per budget (4,096 at the default 65,536)."""
+    return 128 * -(-syn_budget // (SLOT_CHUNKS * 128))
+
+
+def slot_owner(seg_end: jnp.ndarray, window: int, lo=0) -> jnp.ndarray:
+    """owner[j] = #{k : seg_end[k] <= lo + j} for j in [0, window) — equal
+    to ``searchsorted(seg_end, lo + j, side="right")`` but computed by
+    scattering a unit bump at each segment end (shifted by ``lo``: ends at
+    or before ``lo`` count toward slot ``lo``) and taking an inclusive
+    cumsum: O(window + K) instead of O(window · log K)."""
+    bump = jnp.zeros(window + 1, jnp.int32).at[
+        jnp.clip(seg_end - lo, 0, window)].add(1)
+    return jnp.cumsum(bump[:window])
+
+
+def fanout_segments(ids: jnp.ndarray, indptr: jnp.ndarray, *,
+                    invalid_from: int):
+    """``(starts, seg_end)``, each ``[K]``: where each valid id's fan-out
+    run starts in the synapse store, and the inclusive cumsum of the run
+    lengths (invalid ids, ``>= invalid_from``, have empty runs), so
+    ``seg_end[-1]`` is the total synapse count the ids request."""
+    valid = ids < invalid_from
+    safe = jnp.minimum(ids, invalid_from - 1)
+    starts = jnp.where(valid, indptr[safe], 0)
+    lens = jnp.where(valid, indptr[safe + 1] - indptr[safe], 0)
+    return starts, jnp.cumsum(lens)
+
+
+def window_slots(starts, seg_end, syn_budget: int, lo, window: int,
+                 gather_size: int):
+    """Gather indices and validity of budget slots ``[lo, lo + window)``
+    given :func:`fanout_segments`' runs; slots at or beyond
+    ``min(total, syn_budget)`` are invalid."""
+    k = seg_end.shape[0]
+    owner = jnp.minimum(slot_owner(seg_end, window, lo), k - 1)
+    prev_end = jnp.where(owner > 0, seg_end[owner - 1], 0)
+    slot = lo + jnp.arange(window, dtype=jnp.int32)
+    syn_ix = jnp.clip(starts[owner] + slot - prev_end, 0, gather_size - 1)
+    ok = slot < jnp.minimum(seg_end[-1], syn_budget)
+    return syn_ix, ok
 
 
 @jax.named_scope(scopes.SLOT_OWNER)
 def ragged_slots(ids: jnp.ndarray, indptr: jnp.ndarray, syn_budget: int, *,
-                 invalid_from: int, gather_size: int):
+                 invalid_from: int, gather_size: int, lo=0,
+                 window: int | None = None):
     """Assign the fan-out synapse runs of compacted ``ids`` to a bounded
-    flat slot budget.
+    flat slot budget, one window of it at a time.
 
     ``ids`` is a ``[K]`` compacted index list (from
     :func:`two_level_active` or an all-gather of such lists) where any
     value ``>= invalid_from`` marks an unused slot.  ``indptr`` is the
     ``[invalid_from + 1]`` CSR row-pointer array of the synapse store the
     caller will gather from; ``gather_size`` bounds the produced indices
-    (the store's first-axis length).
+    (the store's first-axis length).  ``lo`` (may be traced) and the
+    static ``window`` (default: the whole budget) select budget slots
+    ``[lo, lo + window)``.
 
-    Returns ``(syn_ix [S_cap] i32, ok [S_cap] bool, total i32)``: gather
+    Returns ``(syn_ix [window] i32, ok [window] bool, total i32)``: gather
     indices per slot, slot validity, and the total synapse count requested
-    by the valid ids (``total - sum(ok)`` synapses were dropped to the
-    budget).  Cost: O(S_cap + K), independent of the store size.
+    by the valid ids (``total - min(total, syn_budget)`` synapses were
+    dropped to the budget).  Cost: O(window + K), independent of the
+    store size.
     """
-    k = ids.shape[0]
-    valid = ids < invalid_from
-    safe = jnp.minimum(ids, invalid_from - 1)
-    starts = jnp.where(valid, indptr[safe], 0)
-    lens = jnp.where(valid, indptr[safe + 1] - indptr[safe], 0)
-    seg_end = jnp.cumsum(lens)
-    total = seg_end[-1]
-    owner = slot_owner(seg_end, syn_budget)
-    owner_c = jnp.minimum(owner, k - 1)
-    prev_end = jnp.where(owner_c > 0, seg_end[owner_c - 1], 0)
-    slot = jnp.arange(syn_budget, dtype=jnp.int32)
-    syn_ix = jnp.clip(starts[owner_c] + slot - prev_end, 0, gather_size - 1)
-    ok = slot < jnp.minimum(total, syn_budget)
-    return syn_ix, ok, total
+    starts, seg_end = fanout_segments(ids, indptr, invalid_from=invalid_from)
+    syn_ix, ok = window_slots(starts, seg_end, syn_budget, lo,
+                              window or syn_budget, gather_size)
+    return syn_ix, ok, seg_end[-1]
+
+
+def deliver_ragged(ids: jnp.ndarray, indptr: jnp.ndarray, tgt: jnp.ndarray,
+                   w: jnp.ndarray, syn_budget: int, *, invalid_from: int,
+                   n_out: int):
+    """Deliver the fan-out synapses of compacted ``ids`` into ``[n_out]``
+    targets, up to ``syn_budget`` of them in ascending slot order — the
+    activity-bounded slot loop (module docstring).
+
+    ``indptr``/``tgt``/``w`` are a source-major synapse store (row
+    pointers over ``invalid_from`` sources, targets in ``[0, n_out)``,
+    weights).  Returns ``(g [n_out], total i32, chunks_run i32)``: the
+    summed weights per target, the synapse count the ids requested, and
+    the trips the loop ran, ``ceil(min(total, syn_budget) / C)``.
+    """
+    window = slot_chunk(syn_budget)
+    with jax.named_scope(scopes.SLOT_OWNER):
+        starts, seg_end = fanout_segments(ids, indptr,
+                                          invalid_from=invalid_from)
+        total = seg_end[-1]
+        trips = (jnp.minimum(total, syn_budget) + window - 1) // window
+
+    def chunk(carry):
+        i, g = carry
+        with jax.named_scope(scopes.SLOT_OWNER):
+            syn_ix, ok = window_slots(starts, seg_end, syn_budget,
+                                      i * window, window, tgt.shape[0])
+        with jax.named_scope(scopes.SYN_GATHER):
+            contrib = jnp.where(ok, w[syn_ix], 0)
+            t = jnp.where(ok, tgt[syn_ix], n_out)
+        with jax.named_scope(scopes.SYN_SCATTER):
+            g = g.at[t].add(contrib)
+        return i + 1, g
+
+    g0 = jnp.zeros(n_out + 1, w.dtype)
+    _, g = jax.lax.while_loop(lambda c: c[0] < trips, chunk,
+                              (jnp.int32(0), g0))
+    return g[:n_out], total, trips
 
 
 @jax.named_scope(scopes.DROP_COUNT)
@@ -144,5 +224,7 @@ def active_fanout_total(spikes: jnp.ndarray, indptr: jnp.ndarray):
     return jnp.sum(jnp.where(spikes, fo, 0))
 
 
-__all__ = ["BLOCK", "active_fanout_total", "derived_block_capacity",
-           "n_blocks", "ragged_slots", "slot_owner", "two_level_active"]
+__all__ = ["BLOCK", "SLOT_CHUNKS", "active_fanout_total", "deliver_ragged",
+           "derived_block_capacity", "fanout_segments", "n_blocks",
+           "ragged_slots", "slot_chunk", "slot_owner", "two_level_active",
+           "window_slots"]

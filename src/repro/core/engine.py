@@ -33,7 +33,7 @@ from repro import obs
 
 from .capacity import MONOLITHIC_CAPACITY, CapacityConfig, merge_legacy_capacity
 from .connectome import Connectome
-from .engines import available_engines, get_engine
+from .engines import available_engines, engine_init_stats, get_engine
 from .health import (HealthConfig, SimCheckpointer, carry_counters,
                      health_stats_init, run_chunked)
 from .neuron import LIFParams, LIFState, init_state
@@ -155,7 +155,7 @@ def _init_carry(n: int, cfg: SimConfig, stimulus, seed: int) -> SimCarry:
         counts=jnp.zeros(n, jnp.int32),
         dropped=jnp.int32(0),
         stim=stimulus.init_state(n),
-        stats=health_stats_init(cfg),
+        stats={**engine_init_stats(cfg.engine), **health_stats_init(cfg)},
     )
 
 

@@ -19,8 +19,9 @@ See ``docs/engines.md`` for the comparison matrix and
 :mod:`repro.core.engines.base` for the :class:`DeliveryEngine` protocol.
 """
 
-from .base import (DeliveryEngine, available_engines, engine_integrates_lif,
-                   get_engine, register, register_state, static_field)
+from .base import (DeliveryEngine, available_engines, engine_init_stats,
+                   engine_integrates_lif, get_engine, register,
+                   register_state, static_field)
 from . import (binned, blocked, blocked_fused, csr, dense, ell,  # noqa: F401
                event)
 from .binned import BinnedEngine, BinnedState
@@ -32,7 +33,8 @@ from .ell import EllEngine, EllState
 from .event import Capacity, EventEngine, EventState, auto_capacity
 
 __all__ = [
-    "DeliveryEngine", "available_engines", "engine_integrates_lif",
+    "DeliveryEngine", "available_engines", "engine_init_stats",
+    "engine_integrates_lif",
     "get_engine", "register", "register_state", "static_field", "Capacity",
     "auto_capacity", "BinnedEngine", "BinnedState", "BlockedEngine",
     "BlockedFusedEngine", "BlockedState", "CsrEngine", "CsrState",

@@ -79,6 +79,13 @@ class DeliveryEngine(Protocol):
     ``deliver`` + the separate LIF update — the flag is what guarantees
     integration happens exactly once per step (never zero, never twice).
     Engines without the attribute are unfused (the default).
+
+    Optional counters: an engine that counts its own work defines
+    ``init_stats() -> dict`` of zero scalar counters and
+    ``deliver_with_stats(state, spikes, cfg) -> (g_units, dropped,
+    stats)``; the scan accumulates them into ``SimResult.stats`` (the
+    ``event`` engine's ``slot_chunks``).  ``deliver`` keeps its two
+    values either way.
     """
 
     name: str
@@ -122,6 +129,13 @@ def engine_integrates_lif(name: str) -> bool:
     ``integrates_lif`` capability) — the one place exchange schemes ask
     whether the step body's separate LIF update must be skipped."""
     return bool(getattr(get_engine(name), "integrates_lif", False))
+
+
+def engine_init_stats(name: str) -> dict:
+    """Zero-initialized counters of ``name``'s engine ({} for engines
+    that count nothing) — the monolithic carry's scheme stats."""
+    init = getattr(get_engine(name), "init_stats", None)
+    return init() if init is not None else {}
 
 
 # --------------------------------------------------------------------------

@@ -6,10 +6,13 @@ their fan-out synapse ranges into a bounded synapse budget, and scatter-adds
 into targets.  Cost ∝ activity — the paper's "performance advantages
 increase with sparser activity" path.
 
-Per-step cost is O(n/B + B_cap·B + S_cap) where B = 128 (the compaction
-block), B_cap = ``block_capacity`` and S_cap = ``syn_budget`` — the only
-O(n) work left is vectorized elementwise (the block any-reduce and the
-drop-accounting fan-out dot), not the O(n) compaction scan the flat
+Per-step cost is O(n/B + B_cap·B + ⌈min(events, S_cap)/C⌉·C) where B =
+128 (the compaction block), B_cap = ``block_capacity``, S_cap =
+``syn_budget`` and C = :func:`repro.core.compaction.slot_chunk` — the
+slot loop visits only the chunks of the budget that this step's events
+fill (counted in the ``slot_chunks`` stat).  The only O(n) work left is
+vectorized elementwise (the block any-reduce and the drop-accounting
+fan-out dot), not the O(n) compaction scan the flat
 ``jnp.where(spikes, size=K)`` used to pay regardless of activity.
 
 Capacity overruns are *counted* (``dropped``), never silent — including
@@ -29,8 +32,8 @@ import numpy as np
 from repro.obs import scopes
 
 from ..capacity import CapacityConfig
-from ..compaction import (BLOCK, active_fanout_total, derived_block_capacity,
-                          n_blocks, ragged_slots, slot_owner,
+from ..compaction import (BLOCK, active_fanout_total, deliver_ragged,
+                          derived_block_capacity, n_blocks, slot_owner,
                           two_level_active)
 from ..compress import quantize_weights
 from ..connectome import Connectome
@@ -81,10 +84,10 @@ def auto_capacity(c: Connectome, rate_hz: float, dt_ms: float = 0.1,
       over-provisions the common case by ~margin² (the margin already in
       ``spike_capacity`` gets multiplied in again).
 
-    The budgets directly price the per-step O(B_cap·128 + S_cap) slot
-    work, so tight joint provisioning is itself the perf optimisation;
-    drops stay exactly counted, so any residual under-provisioning is
-    observable rather than silent.
+    ``block_capacity`` prices the per-step O(B_cap·128) compaction and
+    ``syn_budget`` caps the slot work, which otherwise follows the events
+    delivered; drops stay exactly counted, so any residual
+    under-provisioning is observable rather than silent.
     """
     exp_spikes = max(1.0, c.n * rate_hz * dt_ms * 1e-3)
     kp = margin * exp_spikes
@@ -123,22 +126,26 @@ class EventEngine:
             out_tgt=jnp.asarray(c.out_indices),
             out_w=jnp.asarray(ow.astype(np.float32)), n=c.n)
 
+    def init_stats(self) -> dict:
+        return {"slot_chunks": jnp.int32(0)}
+
     def deliver(self, state: EventState, spikes: jax.Array, cfg):
+        g, dropped, _ = self.deliver_with_stats(state, spikes, cfg)
+        return g, dropped
+
+    def deliver_with_stats(self, state: EventState, spikes: jax.Array, cfg):
+        """:meth:`deliver` plus the ``slot_chunks`` counter: the trips the
+        slot loop ran this step."""
         n = state.n
         cap = cfg.capacity
         bcap = cap.block_capacity or derived_block_capacity(
             n, cap.spike_capacity)
         act_idx = two_level_active(spikes, cap.spike_capacity, bcap)
-        syn_ix, ok, total = ragged_slots(
-            act_idx, state.out_indptr, cap.syn_budget,
-            invalid_from=n, gather_size=state.out_tgt.shape[0])
-        with jax.named_scope(scopes.SYN_GATHER):
-            contrib = jnp.where(ok, state.out_w[syn_ix], 0.0)
-            tgt = jnp.where(ok, state.out_tgt[syn_ix], n)
-        with jax.named_scope(scopes.SYN_SCATTER):
-            g = jax.ops.segment_sum(contrib, tgt, num_segments=n + 1)[:n]
+        g, total, chunks = deliver_ragged(
+            act_idx, state.out_indptr, state.out_tgt, state.out_w,
+            cap.syn_budget, invalid_from=n, n_out=n)
         requested = active_fanout_total(spikes, state.out_indptr)
         with jax.named_scope(scopes.DROP_COUNT):
             delivered = jnp.minimum(total, cap.syn_budget)
             dropped = requested - delivered
-        return g, dropped
+        return g, dropped, {"slot_chunks": chunks}

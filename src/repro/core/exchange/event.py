@@ -18,7 +18,8 @@ import jax.numpy as jnp
 
 from repro.obs import scopes
 
-from ..compaction import derived_block_capacity, ragged_slots, two_level_active
+from ..compaction import (deliver_ragged, derived_block_capacity,
+                          two_level_active)
 from .arrays import build_dist_arrays
 from .base import Topology, register_scheme
 
@@ -51,23 +52,19 @@ def capacity_overflow_fanout(delayed, idx, src_gfo, U: int):
 
 
 def deliver_events(events: jax.Array, out_indptr, out_tgt, out_w,
-                   U: int, n_glob: int, syn_budget: int
-                   ) -> tuple[jax.Array, jax.Array]:
-    """events: [E] global ids (pad = n_glob).  Bounded ragged gather via the
-    shared :func:`repro.core.compaction.ragged_slots` — the same code path
-    the monolithic event engine runs, applied to the all-gathered event
-    list against this partition's source-major local store."""
-    syn_ix, ok, total = ragged_slots(
-        events, out_indptr, syn_budget,
-        invalid_from=n_glob, gather_size=out_tgt.shape[0])
-    with jax.named_scope(scopes.SYN_GATHER):
-        contrib = jnp.where(ok, out_w[syn_ix], 0.0)
-        tgt = jnp.where(ok, out_tgt[syn_ix], U)
-    with jax.named_scope(scopes.SYN_SCATTER):
-        g = jax.ops.segment_sum(contrib, tgt, num_segments=U + 1)[:U]
+                   U: int, n_glob: int, syn_budget: int):
+    """events: [E] global ids (pad = n_glob).  Bounded ragged delivery via
+    the shared :func:`repro.core.compaction.deliver_ragged` — the same slot
+    loop the monolithic event engine runs, applied to the all-gathered
+    event list against this partition's source-major local store.
+
+    Returns ``(g [U], dropped, chunks_run)``."""
+    g, total, chunks = deliver_ragged(events, out_indptr, out_tgt, out_w,
+                                      syn_budget, invalid_from=n_glob,
+                                      n_out=U)
     with jax.named_scope(scopes.DROP_COUNT):
         dropped = jnp.maximum(total - syn_budget, 0)
-    return g, dropped
+    return g, dropped, chunks
 
 
 @register_scheme
@@ -78,7 +75,7 @@ class EventExchange:
         return build_dist_arrays(d)
 
     def init_stats(self) -> dict:
-        return {}
+        return {"slot_chunks": jnp.int32(0)}
 
     def exchange(self, state, delayed, cap, topo: Topology):
         return gather_active_events(delayed, cap, topo)
@@ -86,9 +83,10 @@ class EventExchange:
     def deliver(self, state, payload, delayed, sim, cap, topo: Topology):
         events, idx = payload
         U, n_glob = topo.part_size, topo.n_global
-        g, drop = deliver_events(events, state.out_indptr, state.out_tgt,
-                                 state.out_w, U, n_glob, cap.syn_budget)
+        g, drop, chunks = deliver_events(events, state.out_indptr,
+                                         state.out_tgt, state.out_w, U,
+                                         n_glob, cap.syn_budget)
         with jax.named_scope(scopes.DROP_COUNT):
             drop = drop.astype(jnp.int32) + capacity_overflow_fanout(
                 delayed, idx, state.src_gfo, U)
-        return g, drop, {}
+        return g, drop, {"slot_chunks": chunks}

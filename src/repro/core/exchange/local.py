@@ -30,7 +30,11 @@ class LocalExchange:
 
     def deliver(self, state, payload, delayed, sim, cap, topo: Topology):
         from ..engines import get_engine
-        g, drop = get_engine(sim.engine).deliver(state, payload, sim)
+        engine = get_engine(sim.engine)
+        counted = getattr(engine, "deliver_with_stats", None)
+        if counted is not None:
+            return counted(state, payload, sim)
+        g, drop = engine.deliver(state, payload, sim)
         return g, drop, {}
 
     # -- fused-integration capability: delegated to the engine registry --

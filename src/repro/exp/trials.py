@@ -60,6 +60,7 @@ class TrialResult(NamedTuple):
     state: LIFState        # leaves [B, n]
     records: dict          # probe records, each [B, T, ...]
     seeds: tuple           # the seeds, in batch order
+    stats: dict = {}       # engine + health counters, each [B]
 
     def rates_hz(self, t_steps: int, dt_ms: float) -> np.ndarray:
         """[B, n] per-trial per-neuron rates."""
@@ -119,7 +120,8 @@ def run_trials(
                 carry, records = _run_scan_trials(syn, carry, stimulus, cfg,
                                                   probes, t_steps, n)
     return TrialResult(counts=carry.counts, dropped=carry.dropped,
-                       state=carry.lif, records=records, seeds=seeds)
+                       state=carry.lif, records=records, seeds=seeds,
+                       stats=dict(carry.stats))
 
 
 class DistTrialResult(NamedTuple):

@@ -115,7 +115,8 @@ def main():
         dropped=sd((Pn,), jnp.int32),
         # state structure must match the stimulus (Compose.step zips them)
         stim=stim.init_state(U),
-        stats=scheme.init_stats(),
+        stats={k: sd((Pn,) + jnp.shape(v), v.dtype)
+               for k, v in scheme.init_stats().items()},
     )
     topo = Topology(Pn, U, axis=AXIS)
 

@@ -99,7 +99,7 @@ def test_distributed_event_overflow_exact_vs_numpy(setup):
 
         total_drop = 0
         for p in range(P_):
-            g, bdrop = deliver_events(
+            g, bdrop, _ = deliver_events(
                 events, arrs.out_indptr[p], arrs.out_tgt[p], arrs.out_w[p],
                 U, n_glob, budget)
             flat = np.concatenate(
