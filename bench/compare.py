@@ -27,6 +27,9 @@ class Answer:
     g: np.ndarray          # [L, n] synaptic current, same units as v
     refrac: np.ndarray     # [L, n] refractory steps left
     dropped: np.ndarray    # [L] synapse events not delivered
+    # the program's integer counters (``stats`` of its result), each
+    # summed over chips, per lane or a total; never compared
+    stats: dict = dataclasses.field(default_factory=dict)
 
 
 def _mv(x: np.ndarray, model: dict) -> np.ndarray:

@@ -97,7 +97,8 @@ def _span_at(host: list, t: float) -> str:
 
 def reduce(data: dict, chips: int) -> Reduction:
     """Busy, idle and collective time of the first ``chips`` TPUs over the
-    host span ``window``."""
+    host span ``window``; an op may carry fields after its fourth
+    (``bench.layers.load``'s ``op_name``), which are not read here."""
     wins = [(s, s + d) for name, s, d in data["host"] if name == "window"]
     if len(wins) != 1:
         raise ValueError(f"expected one 'window' span, found {len(wins)}")
@@ -111,7 +112,7 @@ def reduce(data: dict, chips: int) -> Reduction:
     for p in planes:
         ops = [(name, max(s, ws), min(s + d, we),
                 bool(COLLECTIVE.search(f"{name} {op}")))
-               for name, op, s, d in data["devices"][p]
+               for name, op, s, d, *_ in data["devices"][p]
                if s < we and s + d > ws and op not in CONTAINERS]
         segs = _union([[s, e] for _, s, e, _ in ops])
         busy.append(sum(e - s for s, e in segs))

@@ -44,4 +44,5 @@ def call(store: dict, stim_seed: int, lane_seeds: list[int]):
 def fetch(r) -> Answer:
     v, g, refrac = (np.asarray(x) for x in r.state)
     return Answer(counts=np.asarray(r.counts)[None], v=v[None], g=g[None],
-                  refrac=refrac[None], dropped=np.asarray([r.dropped]))
+                  refrac=refrac[None], dropped=np.asarray([r.dropped]),
+                  stats=program.counters(r.stats))

@@ -22,7 +22,8 @@ def call(store: dict, stim_seed: int, lane_seeds: list[int]):
 
 
 def fetch(r) -> Answer:
-    counts, (v, g, refrac), dropped = jax.device_get(
-        (r.counts, tuple(r.state), r.dropped))
+    counts, (v, g, refrac), dropped, stats = jax.device_get(
+        (r.counts, tuple(r.state), r.dropped, r.stats))
     return Answer(counts=counts[None], v=v[None], g=g[None],
-                  refrac=refrac[None], dropped=dropped.reshape(1))
+                  refrac=refrac[None], dropped=dropped.reshape(1),
+                  stats=program.counters(stats))

@@ -22,6 +22,7 @@ def call(store: dict, stim_seed: int, lane_seeds: list[int]):
 
 
 def fetch(r) -> Answer:
-    counts, (v, g, refrac), dropped = jax.device_get(
-        (r.counts, tuple(r.state), r.dropped))
-    return Answer(counts=counts, v=v, g=g, refrac=refrac, dropped=dropped)
+    counts, (v, g, refrac), dropped, stats = jax.device_get(
+        (r.counts, tuple(r.state), r.dropped, r.stats))
+    return Answer(counts=counts, v=v, g=g, refrac=refrac, dropped=dropped,
+                  stats=program.counters(stats))

@@ -5,18 +5,27 @@
 A cell (an entry of ``workloads`` in ``BENCHMARK.json``) names a
 configuration (``bench/configs/<name>.json``) and a traffic mix
 (``bench/traffic/<name>.json``); the traffic names the entry point
-(``bench/entries/<name>.py``) and the configuration its plain reference
-(``bench/references/<name>.py``).  End-to-end metrics are read by
-``bench/end_to_end/<metric>.py`` and per-layer metrics by
+(``bench/entries/<name>.py``), and the configuration its network generator
+(``bench/networks/<generator>.py``, by ``network.generator``), its plain
+reference (``bench/references/<name>.py``) and, as data, its model's
+required work (``work``, read by ``bench/work.py``).  End-to-end metrics
+are read by ``bench/end_to_end/<metric>.py`` and per-layer metrics by
 ``bench/metrics/<metric>.py``, each found by its name in ``BENCHMARK.json``.
 
-A run: find the chips (or exit non-zero with no result), make the
-connectome on the device from ``--seed``, build the entry's state, warm up
-one call of the cell's shape (all of this is ``setup_s``), then run whole
-calls until ``--seconds`` have passed; the window ends with the last call,
-whose counts and state are on the host.  Call ``i`` draws its stimulus and
-RNG streams from ``(--seed, i)``.  With ``--trace 1`` the window runs under
-the profiler and the per-layer metrics are reduced from its trace.  After
+A run: find the chips (or exit non-zero with no result), make the network
+on the device from the configuration's fixed ``network.seed``, build the
+entry's state, warm up one call of the cell's shape (all of this is
+``setup_s``), then run whole calls until ``--seconds`` have passed; the
+window ends with the last call, whose counts, state and program counters are
+on the host.  Call ``i`` draws its stimulus and RNG streams from
+``(--seed, i)``: every seed gives the same network and, where the traffic
+fixes it, the same stimulated population, and its own Poisson and
+background draws, so the work of a run does not depend on the seed.  With
+``--trace 1`` the warm-up also keeps the optimized HLO of the programs it
+ran, the window runs under the profiler, and after it the trace is reduced
+to busy, idle and collective time and, by the step's named layers, to
+device time per layer (``bench/layers.py``); the per-layer readers see
+that and the window's program counters (``bench/work.Measure``).  After
 the window, one call drawn from the seed is simulated again by the plain
 reference and compared (``bench/compare.py``); the numbers compared and
 their limits are the last lines on standard error and the last key of the
@@ -40,7 +49,7 @@ from types import ModuleType
 
 import numpy as np
 
-from bench import netgen, program
+from bench import layers, program
 from bench.compare import compared
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
@@ -59,8 +68,8 @@ def _load_json(path: str) -> dict:
         return json.load(f)
 
 
-def _load_module(kind: str, name: str) -> ModuleType:
-    path = os.path.join(BENCH, kind, f"{name}.py")
+def _load_module(root: str, kind: str, name: str) -> ModuleType:
+    path = os.path.join(root, kind, f"{name}.py")
     if not os.path.isfile(path):
         raise FileNotFoundError(path)
     spec = importlib.util.spec_from_file_location(f"bench.{kind}.{name}",
@@ -77,6 +86,7 @@ class Cell:
     config: dict
     traffic: dict
     entry: ModuleType
+    network: ModuleType     # generate(spec, seed), connectome(net)
     reference: ModuleType
     end_to_end: list        # [(spec, reader module)] this cell reports
     per_layer: list
@@ -96,21 +106,34 @@ def load_cell(name: str) -> Cell:
     return cell_from(cells[name], bench)
 
 
-def cell_from(w: dict, bench: dict) -> Cell:
-    """Resolve the files a workload entry ``w`` names; its metrics are
-    those of ``bench`` that it reports."""
+def cell_from(w: dict, bench: dict, root: str = BENCH) -> Cell:
+    """Resolve the files a workload entry ``w`` names, under ``root`` (the
+    benchmark's directory); its metrics are those of ``bench`` that it
+    reports.  A file that is not there is refused here, before any run."""
     name = w["name"]
-    config = _load_json(os.path.join(BENCH, "configs", f"{w['config']}.json"))
-    traffic = _load_json(os.path.join(BENCH, "traffic",
+    config = _load_json(os.path.join(root, "configs", f"{w['config']}.json"))
+    traffic = _load_json(os.path.join(root, "traffic",
                                       f"{w['traffic']}.json"))
     return Cell(
         name=name, chips=int(w["chips"]), config=config, traffic=traffic,
-        entry=_load_module("entries", traffic["entry"]),
-        reference=_load_module("references", config["reference"]),
-        end_to_end=[(m, _load_module("end_to_end", m["name"]))
+        entry=_load_module(root, "entries", traffic["entry"]),
+        network=_load_module(root, "networks",
+                             config["network"]["generator"]),
+        reference=_load_module(root, "references", config["reference"]),
+        end_to_end=[(m, _load_module(root, "end_to_end", m["name"]))
                     for m in bench["end_to_end"] if _reports(m, name)],
-        per_layer=[(m, _load_module("metrics", m["name"]))
+        per_layer=[(m, _load_module(root, "metrics", m["name"]))
                    for m in bench["per_layer"] if _reports(m, name)])
+
+
+def network(cell: Cell):
+    """``(net, conn)``: the configuration's network, drawn from its fixed
+    ``network.seed`` by the generator it names (one graph for every run,
+    as a connectome is one graph), and the simulator's objects built from
+    it.  Every run, reading and per-layer split takes its network here."""
+    spec = cell.config["network"]
+    net = cell.network.generate(spec, int(spec["seed"]))
+    return net, cell.network.connectome(net)
 
 
 # --------------------------------------------------------------------------
@@ -126,15 +149,21 @@ def _seeds(seed: int, path: tuple, k: int) -> list[int]:
     return [int(x) & 0x7FFFFFFF for x in st]
 
 
-def network_seed(seed: int) -> int:
-    return _seeds(seed, (0,), 1)[0]
-
-
-def call_seeds(seed: int, i: int, lanes: int) -> tuple[int, list[int]]:
+def call_seeds(seed: int, i: int, traffic: dict) -> tuple[int, list[int]]:
     """Call ``i``'s stimulus seed and one RNG seed per lane (``i = -1`` is
-    the warm-up call)."""
-    s = _seeds(seed, (1, i + 1), lanes + 1)
-    return s[0], s[1:]
+    the warm-up call), drawn from ``seed``.  A traffic that states
+    ``streams_seed`` draws them from that fixed seed instead, so that every
+    run's call ``i`` carries the same set of lane streams, and ``seed`` only
+    orders them across the lanes."""
+    lanes = int(traffic["lanes"])
+    fixed = traffic.get("streams_seed")
+    s = _seeds(seed if fixed is None else fixed, (1, i + 1), lanes + 1)
+    lane_seeds = s[1:]
+    if fixed is not None:
+        order = np.random.default_rng(_entropy(seed, 4, i + 1)).permutation(
+            lanes)
+        lane_seeds = [lane_seeds[k] for k in order]
+    return s[0], lane_seeds
 
 
 def sample_call(seed: int, n_calls: int) -> int:
@@ -202,6 +231,14 @@ def _events_delivered(answers, fan_out: np.ndarray) -> int:
                    for a in answers))
 
 
+def _counters(answers, n_steps: int) -> dict:
+    """The program's counters over the window's calls, per simulated step,
+    summed over lanes and chips."""
+    keys = set.intersection(*(set(a.stats) for a in answers))
+    return {k: sum(int(np.sum(a.stats[k])) for a in answers) / n_steps
+            for k in sorted(keys)}
+
+
 def _say(*parts) -> None:
     print("[bench]", *parts, file=sys.stderr, flush=True)
 
@@ -218,20 +255,23 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
 
     marks = [("start", t_start), ("devices", time.monotonic())]
     with jax.profiler.TraceAnnotation("setup"):
-        net = netgen.generate(cfg["network"], network_seed(seed))
-        conn = program.connectome(net)
+        net, conn = network(cell)
         marks.append(("network", time.monotonic()))
         store = cell.entry.build(conn, cfg, tr)
         marks.append(("build", time.monotonic()))
-        with jax.profiler.TraceAnnotation("warmup"):
+        # a traced run keeps the HLO of the programs the warm-up ran: a TPU
+        # trace names an operation only by its instruction
+        with jax.profiler.TraceAnnotation("warmup"), (
+                layers.compiled_programs() if trace
+                else contextlib.nullcontext({})) as programs:
             cell.entry.fetch(cell.entry.call(store, *call_seeds(seed, -1,
-                                                                lanes)))
+                                                                tr)))
         marks.append(("warmup", time.monotonic()))
     setup_s = marks[-1][1] - t_start
     _say(f"setup {setup_s:.3f} s (" + ", ".join(
         f"{b[0]} {b[1] - a[1]:.2f} s" for a, b in zip(marks, marks[1:]))
         + f"): network n={net.n} synapses={net.nnz}, seed "
-        f"{network_seed(seed)}")
+        f"{cfg['network']['seed']}")
 
     tdir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
     answers = []
@@ -239,15 +279,17 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                                       else contextlib.nullcontext()):
         with jax.profiler.TraceAnnotation("window"):
             t0 = time.monotonic()
+            ends = []
             while True:
                 with jax.profiler.TraceAnnotation("call"):
                     r = cell.entry.call(store,
-                                        *call_seeds(seed, len(answers), lanes))
+                                        *call_seeds(seed, len(answers), tr))
                 with jax.profiler.TraceAnnotation("fetch"):
                     answers.append(cell.entry.fetch(r))
-                if time.monotonic() - t0 >= seconds:
+                ends.append(time.monotonic())
+                if ends[-1] - t0 >= seconds:
                     break
-            window_s = time.monotonic() - t0
+            window_s = ends[-1] - t0
     peak = memory_peak(devices)
     del r, store
     gc.collect()
@@ -257,8 +299,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                          f"missed a shape")
     events = _events_delivered(answers, np.diff(net.out_indptr))
     n_steps = len(answers) * steps
+    counters = _counters(answers, n_steps)
     _say(f"window {window_s:.3f} s: {len(answers)} calls x {steps} steps x "
-         f"{lanes} lanes, compilations in window 0")
+         f"{lanes} lanes, compilations in window 0; seconds per call "
+         f"{[round(b - a, 4) for a, b in zip([t0] + ends, ends)]}")
     _say(f"synapse events delivered per step {events / n_steps:.1f} "
          f"(sum of count x fan-out over {n_steps} steps, all lanes); "
          f"spikes per call {[int(a.counts.sum()) for a in answers]}; "
@@ -274,9 +318,22 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                  steps=steps, lanes=lanes, dt_ms=dt_ms, chips=cell.chips)
     if trace:
         from bench import devtrace, work
-        red = devtrace.reduce(devtrace.load(tdir), cell.chips)
+        t_red = time.monotonic()
+        data = layers.load(tdir, programs)
         shutil.rmtree(tdir, ignore_errors=True)
-        measure = work.Measure.of(red, win, cfg, events, devices[0])
+        red = devtrace.reduce(data, cell.chips)
+        lay = layers.reduce(data, cell.chips, n_steps, len(answers),
+                            base=red)
+        if lay is not None:
+            _say(f"layers, device ms per step: {lay.layers}; parts of "
+                 f"delivery {lay.parts}; named {100 * lay.named_share:.3f} "
+                 f"% of busy")
+        _say(f"program counters per step: {counters}; trace read and "
+             f"reduced in {time.monotonic() - t_red:.1f} s")
+        measure = work.Measure.of(
+            red, win, cfg["work"], net.n, events, devices[0],
+            layers={} if lay is None else {**lay.layers, **lay.parts},
+            counters=counters)
         for spec, reader in cell.per_layer:
             value = reader.read(measure)
             if value is not None:
@@ -291,7 +348,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                                                "unit": spec["unit"]}
 
     j = sample_call(seed, len(answers))
-    stim_seed, lane_seeds = call_seeds(seed, j, lanes)
+    stim_seed, lane_seeds = call_seeds(seed, j, tr)
     t_ref = time.monotonic()
     want = cell.reference.run_call(net, cfg["model"], tr, lane_seeds,
                                    stim_seed, layout=cfg.get("partition"))
@@ -311,13 +368,17 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
 
 def enable_compile_cache() -> None:
     """JAX's persistent compilation cache at the checkout's fixed
-    ``bench/.cache/jax``, handed to the simulator's own cache switch, and
-    holding every program (not only those that compile for a second)."""
+    ``bench/.cache/jax``, handed to the simulator's own cache switch,
+    holding every program (not only those that compile for a second), and
+    with each program's metadata in its key: a traced run reads its layers
+    from that metadata, so an executable compiled from a tree without
+    scopes can never stand in for one compiled with them."""
     os.environ["JAX_COMPILATION_CACHE_DIR"] = COMPILE_CACHE
     import jax
     from repro.launch.compile_cache import enable_compile_cache as enable
     jax.config.update("jax_compilation_cache_dir", enable())
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 def main(t_start: float, argv=None) -> int:
@@ -337,6 +398,5 @@ def main(t_start: float, argv=None) -> int:
 
 
 __all__ = ["Cell", "NoChip", "Window", "call_seeds", "cell_from",
-           "enable_compile_cache",
-           "load_cell", "main", "network_seed", "require_devices",
-           "run_cell", "sample_call"]
+           "enable_compile_cache", "load_cell", "main", "network",
+           "require_devices", "run_cell", "sample_call"]

@@ -24,7 +24,7 @@ splits the window's busy time by layer:
 A program that declares scopes but whose trace holds no scoped operation
 is an error, never a row of zeros: a persistent compile cache keyed
 without metadata can hand back an executable compiled from a tree
-without scopes.  ``enable_compile_cache`` keys the cache with the
+without scopes.  ``harness.enable_compile_cache`` keys the cache with the
 programs' metadata for that reason.  A program that declares no scopes
 (an older tree) reduces to nothing.
 
@@ -88,7 +88,9 @@ def layer_of(op_name: str, names) -> str:
 
 
 _INSTR = re.compile(r"\s*(?:ROOT )?%([\w.-]+) = (.*)$")
+_COMPUTATION = re.compile(r"(?:ENTRY )?%?([\w.-]+) ")
 _REF = re.compile(r"%([\w.-]+)")
+_CALLS = re.compile(r"(?:calls|to_apply)=%([\w.-]+)")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
@@ -96,18 +98,31 @@ def hlo_op_names(hlo_text: str) -> tuple[str, dict]:
     """``(module name, {instruction: op_name})`` from a program's
     optimized HLO text.  An instruction that carries no layer scope of its
     own (XLA's passes make some without metadata: copies, the
-    reduce-window trees of a cumulative sum) takes the ``op_name`` of the
-    nearest instruction of its computation that uses its result and has
-    one.  A collective without one is the exchange's: the exchange is the
-    only layer that may communicate (``repro.core.exchange.base``), and
-    the TPU compiler remakes its all-gather as an all-reduce that
-    carries no layer scope."""
+    reduce-window trees of a cumulative sum, a fusion whose root has
+    none) takes, in this order:
+
+    * a collective, the exchange's: the exchange is the only layer that
+      may communicate (``repro.core.exchange.base``), and the TPU compiler
+      remakes its all-gather as an all-reduce that carries no layer scope;
+    * an instruction that calls computations (a fusion, a scatter's or a
+      reduction's combiner), the scope of the operations they hold, from
+      their roots down through each one's own calls and then its operands:
+      what it computes, not what uses it.  The TPU compiler leaves a
+      scatter without metadata, but its combiner keeps the scope (the
+      vmapped compaction's ``where`` scatter, fused and feeding the slot
+      loop, is compaction's; the ``segment_sum`` scatter, fed by the
+      synapse gather, is ``syn_scatter``'s);
+    * otherwise the ``op_name`` of the nearest instruction of its
+      computation that uses its result and has one."""
     module = hlo_text.split(None, 2)[1].rstrip(",")
     names = scope_names()
     every = names[0] + names[1] if names else ()
-    op_name, users, collective = {}, {}, {}
+    op_name, users, operands, collective, calls, root = {}, {}, {}, {}, {}, {}
+    comp = None
     for line in hlo_text.splitlines():
         if not line.startswith(" "):
+            head = _COMPUTATION.match(line)
+            comp = head[1] if head else None
             body = set()             # a new computation: its own names
             continue
         m = _INSTR.match(line)
@@ -119,17 +134,38 @@ def hlo_op_names(hlo_text: str) -> tuple[str, dict]:
         opcode = devtrace.op_label(f"%{name} = {rest}")[1]
         if devtrace.COLLECTIVE.match(opcode):
             collective[name] = opcode
+        head = rest.split(", metadata=")[0]
+        if (called := _CALLS.findall(head)):
+            calls[name] = called
+        if line.lstrip().startswith("ROOT "):
+            root[comp] = name
+        operands[name] = [r for r in set(_REF.findall(head))
+                          if r in body and r != name]
         body.add(name)
-        for ref in set(_REF.findall(rest.split(", metadata=")[0])):
-            if ref in body and ref != name:
-                users.setdefault(ref, []).append(name)
+        for ref in operands[name]:
+            users.setdefault(ref, []).append(name)
     scoped = {n: o for n, o in op_name.items()
               if layer_of(o, every) != OTHER}
     if names:
         from repro.obs.scopes import EXCHANGE
         for name, opcode in collective.items():
             scoped.setdefault(name, f"{EXCHANGE}/{opcode}")
-            op_name[name] = scoped[name]
+
+    def called_scope(name, seen):
+        queue = collections.deque(root.get(c) for c in calls.get(name, ()))
+        while queue:
+            x = queue.popleft()
+            if x is None or x in seen:
+                continue
+            seen.add(x)
+            if (o := scoped.get(x) or called_scope(x, seen)):
+                return o
+            queue.extend(sorted(operands.get(x, ())))
+        return ""
+
+    for name in [n for n in calls if n not in scoped]:
+        if (o := called_scope(name, {name})):
+            scoped[name] = o
 
     def inherit(name):
         queue, seen = collections.deque(users.get(name, ())), {name}
@@ -143,8 +179,7 @@ def hlo_op_names(hlo_text: str) -> tuple[str, dict]:
         return ""
 
     for name, o in op_name.items():
-        if name not in scoped:
-            op_name[name] = inherit(name) or o
+        op_name[name] = scoped.get(name) or inherit(name) or o
     return module, op_name
 
 
@@ -208,9 +243,12 @@ def load(trace_dir: str, programs: dict) -> dict:
                            e.name.split("(")[0])
                           for e in lines.get(MODULES_LINE, ()))
             starts = [r[0] for r in runs]
-            ops = []
+            ops, labels = [], {}
             for e in lines.get(devtrace.OPS_LINE, ()):
-                label, opcode = devtrace.op_label(e.name)
+                name = e.name
+                if name not in labels:
+                    labels[name] = devtrace.op_label(name)
+                label, opcode = labels[name]
                 s = int(e.start_ns)
                 k = bisect.bisect_right(starts, s) - 1
                 module = runs[k][2] if k >= 0 and s < runs[k][1] else ""
@@ -248,18 +286,16 @@ class Layers:
 
 
 def reduce(data: dict, chips: int, steps: int, calls: int,
-           names=None) -> Layers | None:
+           names=None, base=None) -> Layers | None:
     """Split the window of ``data`` (``load``'s lists) by layer; ``steps``
-    and ``calls`` are the window's simulated steps and calls.  None where
-    the program declares no scopes."""
+    and ``calls`` are the window's simulated steps and calls, ``base`` its
+    ``devtrace.reduce`` where the caller has it.  None where the program
+    declares no scopes."""
     names = names if names is not None else scope_names()
     if names is None:
         return None
     layers, parts = names
-    base = devtrace.reduce(
-        {"devices": {p: [op[:4] for op in ops]
-                     for p, ops in data["devices"].items()},
-         "host": data["host"]}, chips)
+    base = base or devtrace.reduce(data, chips)
     (ws, we), = [(s, s + d) for n, s, d in data["host"] if n == "window"]
     planes = sorted(data["devices"],
                     key=lambda p: int(p.rsplit(":", 1)[1]))[:chips]
@@ -315,23 +351,12 @@ def cut(data: dict, start_ns: int, stop_ns: int) -> dict:
 # A run of one cell, on the chip
 # --------------------------------------------------------------------------
 
-def enable_compile_cache() -> None:
-    """The benchmark's compile cache, with each program's metadata in its
-    key: an executable compiled from a tree without scopes can then never
-    stand in for one compiled with them."""
-    from bench import harness
-    harness.enable_compile_cache()
-    import jax
-    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
-
-
 def _window(cell, store, seed: int, seconds: float, first: int,
             trace_dir: str | None):
     """Whole calls, numbered from ``first``, until ``seconds`` have
     passed, with the harness's annotations; ``(wall seconds, calls)``."""
     import jax
     from bench.harness import call_seeds
-    lanes = int(cell.traffic["lanes"])
     n = 0
     with (jax.profiler.trace(trace_dir) if trace_dir
           else contextlib.nullcontext()):
@@ -340,7 +365,8 @@ def _window(cell, store, seed: int, seconds: float, first: int,
             while True:
                 with jax.profiler.TraceAnnotation("call"):
                     r = cell.entry.call(store,
-                                        *call_seeds(seed, first + n, lanes))
+                                        *call_seeds(seed, first + n,
+                                                    cell.traffic))
                 with jax.profiler.TraceAnnotation("fetch"):
                     cell.entry.fetch(r)
                 n += 1
@@ -356,16 +382,15 @@ def run(cell, seed: int, seconds: float, fixture: str | None = None,
     window of ``seconds`` each, and their reduction; the result line's
     object.  ``fixture`` is a path for a cut of the trace."""
     import jax
-    from bench import harness, netgen, program
+    from bench import harness
     if require_tpu:
         harness.require_devices(cell.chips)
     cfg, tr = cell.config, cell.traffic
     steps, lanes = int(tr["steps"]), int(tr["lanes"])
-    net = netgen.generate(cfg["network"], harness.network_seed(seed))
-    store = cell.entry.build(program.connectome(net), cfg, tr)
+    store = cell.entry.build(harness.network(cell)[1], cfg, tr)
     with compiled_programs() as programs:
         cell.entry.fetch(cell.entry.call(
-            store, *harness.call_seeds(seed, -1, lanes)))
+            store, *harness.call_seeds(seed, -1, tr)))
     rates = {}
     tdir = tempfile.mkdtemp(prefix="bench_layers_")
     try:
@@ -420,7 +445,7 @@ def main(argv=None) -> int:
     from bench import harness, program
     program.import_program()
     cell = harness.load_cell(args.workload)
-    enable_compile_cache()
+    harness.enable_compile_cache()
     out = run(cell, args.seed, args.seconds, args.fixture)
     line = json.dumps(out)
     if args.out:
@@ -434,5 +459,5 @@ if __name__ == "__main__":
     sys.exit(main())
 
 
-__all__ = ["Layers", "OTHER", "cut", "enable_compile_cache", "layer_of",
-           "load", "reduce", "run", "scope_names"]
+__all__ = ["Layers", "OTHER", "cut", "layer_of", "load", "reduce", "run",
+           "scope_names"]

@@ -10,6 +10,8 @@ from __future__ import annotations
 import os
 import sys
 
+import numpy as np
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -30,6 +32,15 @@ def connectome(net):
                       in_indices=net.in_indices, in_weights=net.in_weights,
                       out_indptr=net.out_indptr, out_indices=net.out_indices,
                       out_weights=net.out_weights)
+
+
+def counters(stats: dict) -> dict:
+    """The integer counters of a result's ``stats`` (engine, scheme and
+    health counters) as host arrays; any other entry, such as the
+    telemetry's ``compile_cache`` snapshot, is left out."""
+    arrays = {k: np.asarray(v) for k, v in stats.items()}
+    return {k: a for k, a in arrays.items()
+            if np.issubdtype(a.dtype, np.integer)}
 
 
 def capacity(traffic: dict):
@@ -67,13 +78,14 @@ def event_store(conn, config: dict, traffic: dict) -> dict:
 
 def stimulus(conn, cfg, traffic: dict, stim_seed: int):
     """The traffic's named scenario; a scenario that picks a population
-    draws it from ``stim_seed``."""
+    draws it from the traffic's own ``seed`` (a fixed population, the same
+    in every run) or, where the traffic gives none, from ``stim_seed``."""
     from repro.exp import build_scenario, get_scenario
     params = dict(traffic["params"])
     if "seed" in get_scenario(traffic["scenario"]).defaults:
-        params["seed"] = int(stim_seed)
+        params.setdefault("seed", int(stim_seed))
     return build_scenario(traffic["scenario"], conn, cfg, **params)
 
 
-__all__ = ["ROOT", "capacity", "connectome", "event_store", "import_program",
-           "sim_config", "stimulus"]
+__all__ = ["ROOT", "capacity", "connectome", "counters", "event_store",
+           "import_program", "sim_config", "stimulus"]

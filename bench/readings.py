@@ -2,7 +2,7 @@
 
     python3 bench/readings.py --workload <cell> --seeds 12 --control-seeds 3
 
-For each seed, in one process on the cell's chips: the connectome and the
+For each seed, in one process on the cell's chips: the network and the
 stimulus of call 0 as a run with that seed makes them, one call of the
 simulator at the cell's own size, and the plain reference; the numbers
 compared (``bench/compare.py``) are read for the simulator against the
@@ -25,7 +25,7 @@ if __name__ == "__main__":
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     sys.path[0] = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-from bench import harness, netgen, program  # noqa: E402
+from bench import harness, program  # noqa: E402
 from bench.compare import compared  # noqa: E402
 
 
@@ -46,9 +46,9 @@ def main(argv=None) -> int:
     for k in range(args.seeds):
         seed = args.first_seed + k
         t0 = time.monotonic()
-        net = netgen.generate(cfg["network"], harness.network_seed(seed))
-        store = cell.entry.build(program.connectome(net), cfg, tr)
-        stim_seed, lanes = harness.call_seeds(seed, 0, int(tr["lanes"]))
+        net, conn = harness.network(cell)
+        store = cell.entry.build(conn, cfg, tr)
+        stim_seed, lanes = harness.call_seeds(seed, 0, tr)
         got = cell.entry.fetch(cell.entry.call(store, stim_seed, lanes))
         del store
         want = cell.reference.run_call(net, cfg["model"], tr, lanes,

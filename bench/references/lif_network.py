@@ -21,9 +21,10 @@ events as the system under test:
   stimulus part ``j`` (in the traffic's order);
 * a Poisson part on a chosen population draws one Bernoulli(rate*dt) per
   chosen neuron (``default_rng(seed).choice(n, k, replace=False)`` picks
-  them); Loihi drive adds ``weight`` units to ``g``, Brian2 drive adds
-  ``1.5 * v_th`` mV to ``v``; background draws one per neuron and forces a
-  spike;
+  them, ``seed`` the traffic's own where it fixes the population, else the
+  call's stimulus seed); Loihi drive adds ``weight`` units to ``g``, Brian2
+  drive adds ``1.5 * v_th`` mV to ``v``; background draws one per neuron
+  and forces a spike;
 * on a partitioned layout every partition owns a contiguous near-equal
   neuron range, padded to ``pad_multiple`` neurons, with its own key
   (``split(PRNGKey(seed), parts)[p]``), and every part draws over the
@@ -68,7 +69,7 @@ def stimulus_parts(traffic: dict) -> list[dict]:
     sc, prm = traffic["scenario"], traffic["params"]
     if sc == "sugar_feeding":
         parts = [{"kind": "poisson", "n": prm["n_sugar"],
-                  "rate_hz": prm["rate_hz"]}]
+                  "rate_hz": prm["rate_hz"], "seed": prm.get("seed")}]
         if prm.get("background_hz", 0.0) > 0:
             parts.append({"kind": "background",
                           "rate_hz": prm["background_hz"]})
@@ -95,8 +96,10 @@ def drive_events(n: int, traffic: dict, steps: int, lane_seed: int,
     for j, part in enumerate(parts):
         prob = part["rate_hz"] * traffic["dt_ms"] * 1e-3
         full = np.zeros((steps, n), bool)
-        idx = (pick(n, part["n"], stim_seed) if part["kind"] == "poisson"
-               else None)
+        idx = None
+        if part["kind"] == "poisson":
+            idx = pick(n, part["n"], stim_seed if part["seed"] is None
+                       else part["seed"])
         if layout is None:
             shape = (len(idx),) if idx is not None else (n,)
             d = np.asarray(_draws(jax.random.PRNGKey(lane_seed), steps,

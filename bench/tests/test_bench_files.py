@@ -5,6 +5,7 @@ the file keeps to the benchmark's contract."""
 import json
 import os
 import re
+import shutil
 
 import pytest
 
@@ -32,6 +33,8 @@ def test_cell_resolves_every_file(name):
     cell = harness.load_cell(name)
     for fn in ("build", "call", "fetch"):
         assert callable(getattr(cell.entry, fn))
+    for fn in ("generate", "connectome"):
+        assert callable(getattr(cell.network, fn))
     assert callable(cell.reference.run_call)
     e2e = [m["name"] for m, _ in cell.end_to_end]
     assert "setup_s" in e2e and len(e2e) >= 2
@@ -94,3 +97,30 @@ def test_peaks_unknown_kind_raises():
 def test_unknown_workload_is_refused():
     with pytest.raises(SystemExit):
         harness.load_cell("no_such_cell")
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in BENCH["configs"]])
+def test_configuration_names_its_generator(config):
+    """Every configuration names the generator of its network, which is a
+    file of the benchmark's own."""
+    with open(os.path.join(harness.BENCH, "configs", f"{config}.json")) as f:
+        generator = json.load(f)["network"]["generator"]
+    assert NAME.fullmatch(generator)
+    assert os.path.isfile(os.path.join(harness.BENCH, "networks",
+                                       f"{generator}.py"))
+
+
+def test_unknown_generator_is_refused(tmp_path):
+    """A configuration whose generator is not a file is refused when the
+    cell is loaded, before any run."""
+    root = tmp_path / "bench"
+    shutil.copytree(harness.BENCH, root, ignore=shutil.ignore_patterns(
+        ".cache", "__pycache__", "tests"))
+    cfg_path = root / "configs" / "flywire_q19.json"
+    config = json.loads(cfg_path.read_text())
+    config["network"]["generator"] = "no_such_generator"
+    cfg_path.write_text(json.dumps(config))
+    w = {w["name"]: w for w in BENCH["workloads"]}["q19_sugar"]
+    assert harness.cell_from(w, BENCH).network.__name__.endswith("flywire")
+    with pytest.raises(FileNotFoundError, match="no_such_generator"):
+        harness.cell_from(w, BENCH, root=str(root))

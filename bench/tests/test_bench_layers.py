@@ -1,6 +1,7 @@
 """The per-layer reduction of a traced window (``bench/layers.py``): device
 time by the program's named layers, idle time by its host spans."""
 
+import dataclasses
 import json
 import os
 import subprocess
@@ -134,12 +135,13 @@ def test_old_four_field_trace_reduces_as_before():
 
 
 def test_compile_cache_keyed_with_metadata():
-    """The per-layer run keys the compile cache with each program's
-    metadata; the benchmark's own set-up leaves JAX's default alone."""
-    code = ("import jax; from bench import harness, layers; "
+    """The benchmark's set-up, which the traced run and ``bench.layers``
+    share, keys the compile cache with each program's metadata (JAX's
+    default leaves it out)."""
+    code = ("import jax; from bench import harness; "
             "flag = lambda: jax.config.jax_compilation_cache_include_"
-            "metadata_in_key; harness.enable_compile_cache(); "
-            "print(flag()); layers.enable_compile_cache(); print(flag())")
+            "metadata_in_key; print(flag()); "
+            "harness.enable_compile_cache(); print(flag())")
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = os.pathsep.join([ROOT, os.path.join(ROOT, "src")])
     p = subprocess.run([sys.executable, "-c", code], env=env, cwd=ROOT,
@@ -249,6 +251,76 @@ def test_compiled_programs_of_a_call():
             "stimulus", "lif", "ring"} <= found
 
 
+# after the TPU compiler's v5e HLO of the vmapped trials scan: two scatters
+# without metadata, fused; each combiner keeps its layer's scope
+VMAPPED = """HloModule jit__run_scan_trials_jit, is_scheduled=true
+
+%region_7.25 (scatter-add.7: s32[], scatter-add.8: s32[]) -> s32[] {
+  %scatter-add.8 = s32[] parameter(1), metadata={op_name="scatter-add"}
+  %scatter-add.7 = s32[] parameter(0), metadata={op_name="scatter-add"}
+  ROOT %add.346 = s32[] add(%scatter-add.7, %scatter-add.8), metadata={op_name="delivery/compaction/add" stack_frame_id=55}
+}
+
+%region_13.46 (scatter-add.17: f32[], scatter-add.18: f32[]) -> f32[] {
+  %scatter-add.18 = f32[] parameter(1), metadata={op_name="scatter-add"}
+  %scatter-add.17 = f32[] parameter(0), metadata={op_name="scatter-add"}
+  ROOT %add.375 = f32[] add(%scatter-add.17, %scatter-add.18), metadata={op_name="delivery/while/body/syn_scatter/add" stack_frame_id=91}
+}
+
+%fused_computation.14 (param_0.863: s32[2048], param_1.1134: s32[80384], param_2.1064: s32[80384]) -> s32[2048] {
+  %param_0.863 = s32[2048]{0} parameter(0)
+  %param_1.1134 = s32[80384]{0} parameter(1)
+  %reshape.851 = s32[4,20096]{1,0} reshape(%param_1.1134)
+  %param_2.1064 = s32[80384]{0} parameter(2)
+  %reshape.852 = s32[4,20096]{1,0} reshape(%param_2.1064)
+  ROOT %scatter.73 = s32[2048]{0} scatter(%param_0.863, %reshape.851, %reshape.852), update_window_dims={}, inserted_window_dims={0}, scatter_dims_to_operand_dims={0}, index_vector_dim=2, to_apply=%region_7.25
+}
+
+%fused_computation.148 (param_0.864: s32[80384], param_1.1135: s32[80384], param_2.1065: s32[2048]) -> s32[2048] {
+  %param_2.1065 = s32[2048]{0} parameter(2)
+  %param_0.864 = s32[80384]{0} parameter(0)
+  %param_1.1135 = s32[80384]{0} parameter(1)
+  ROOT %fusion.135 = s32[2048]{0} fusion(%param_2.1065, %param_0.864, %param_1.1135), kind=kCustom, calls=%fused_computation.14
+}
+
+%fused_computation.10 (param_0.921: f32[80004], param_1.1175: s32[16384], param_2.1086: f32[16384]) -> f32[80004] {
+  %param_0.921 = f32[80004]{0} parameter(0)
+  %param_1.1175 = s32[16384]{0} parameter(1)
+  %reshape.888 = s32[4,4096]{1,0} reshape(%param_1.1175)
+  %param_2.1086 = f32[16384]{0} parameter(2)
+  %reshape.889 = f32[4,4096]{1,0} reshape(%param_2.1086), metadata={op_name="jit(_run_scan_trials_jit)/vmap()/while/body/closed_call/delivery/while/body/syn_gather/jit(_where)/select_n"}
+  ROOT %scatter.77 = f32[80004]{0} scatter(%param_0.921, %reshape.888, %reshape.889), update_window_dims={}, inserted_window_dims={0}, scatter_dims_to_operand_dims={0}, index_vector_dim=2, to_apply=%region_13.46
+}
+
+%body (arg: (s32[], s32[80384], f32[16384], s32[2048])) -> (s32[], s32[2048], f32[80004]) {
+  %arg = (s32[], s32[80384], f32[16384], s32[2048]) parameter(0)
+  %gte.1 = s32[80384]{0} get-tuple-element(%arg), index=1
+  %gte.2 = f32[16384]{0} get-tuple-element(%arg), index=2
+  %gte.3 = s32[2048]{0} get-tuple-element(%arg), index=3
+  %copy.4 = s32[2048]{0} copy(%gte.3)
+  %fusion.145 = s32[2048]{0} fusion(%gte.1, %gte.1, %copy.4), kind=kCustom, calls=%fused_computation.148
+  %fusion.160 = s32[16384]{0} fusion(%fusion.145), kind=kLoop, calls=%fc, metadata={op_name="jit(_run_scan_trials_jit)/vmap()/while/body/closed_call/delivery/slot_owner/gather"}
+  %fusion.170 = f32[80004]{0} fusion(%gte.2, %fusion.160, %gte.2), kind=kCustom, calls=%fused_computation.10
+  ROOT %tuple.9 = (s32[], s32[2048], f32[80004]) tuple(%gte.1, %fusion.145, %fusion.170)
+}
+"""
+
+
+def test_hlo_scatter_takes_its_combiners_layer():
+    """A fusion without metadata is attributed by what it fuses: the
+    vmapped compaction's ``where`` scatter is compaction's though the slot
+    loop uses it, the ``segment_sum`` scatter is ``syn_scatter``'s though
+    the synapse gather feeds it; an unscoped copy feeding the compaction
+    scatter is compaction's too."""
+    _, names = layers.hlo_op_names(VMAPPED)
+    every = sum(layers.scope_names(), ())
+    by_layer = {k: layers.layer_of(v, every) for k, v in names.items()}
+    assert by_layer["fusion.145"] == "compaction"
+    assert by_layer["copy.4"] == "compaction"
+    assert by_layer["fusion.170"] == "syn_scatter"
+    assert by_layer["fusion.160"] == "slot_owner"
+
+
 FIXTURE = os.path.join(HERE, "fixtures", "trace_q19_sugar_layers.json")
 
 
@@ -267,3 +339,76 @@ def test_recorded_chip_trace_by_layer():
     inside = {k: v for k, v in r.idle.items() if k != "fetch"}
     assert inside and all(k.startswith("repro.") for k in inside)
     assert {"repro.init_carry", "repro.scenario"} <= set(inside)
+
+
+def _recorded_measure(counters):
+    """A ``work.Measure`` of the recorded ``q19_sugar`` cut, as the traced
+    run builds it."""
+    from bench import harness, work
+    with open(FIXTURE) as f:
+        data = json.load(f)
+    red = devtrace.reduce(data, chips=1)
+    lay = layers.reduce(data, chips=1, steps=1, calls=1)
+    win = harness.Window(setup_s=0.0, window_s=red.window_s, calls=1,
+                         steps=1, lanes=1, dt_ms=0.1, chips=1)
+    kind = type("Kind", (), {"device_kind": "TPU v5 lite"})
+    spec = {"neuron_bytes": 34, "neuron_ops": 14, "event_bytes": 16,
+            "event_ops": 1}
+    return lay, work.Measure.of(red, win, spec, 139_255, 0, kind,
+                                layers={**lay.layers, **lay.parts},
+                                counters=counters)
+
+
+@pytest.mark.parametrize("metric,layer", [
+    ("delivery_ms_per_step", "delivery"),
+    ("compaction_ms_per_step", "compaction"),
+    ("delivery_ms_per_step.trials", "delivery"),
+    ("compaction_ms_per_step.trials", "compaction")])
+def test_layer_readers_on_recorded_trace(metric, layer):
+    from bench import harness
+    reader = harness._load_module(harness.BENCH, "metrics", metric)
+    lay, m = _recorded_measure({})
+    assert reader.read(m) == lay.layers[layer] > 0
+    empty = dataclasses.replace(m, layers={})
+    assert reader.read(empty) is None
+
+
+def test_slot_chunks_reader_on_recorded_trace():
+    from bench import harness
+    reader = harness._load_module(harness.BENCH, "metrics",
+                                  "slot_chunks_per_step")
+    _, m = _recorded_measure({"slot_chunks": 0.149})
+    assert reader.read(m) == 0.149
+    assert reader.read(dataclasses.replace(m, counters={})) is None
+
+
+def test_traced_run_reports_layers_and_counters(monkeypatch):
+    """The traced run's plumbing on the CPU, the recorded chip trace standing
+    in for the profiler's: the per-layer metrics of a ``q19_sugar`` run come
+    from its layers and its program counters."""
+    from bench import harness, work
+    from tiny import tiny_cell
+    with open(FIXTURE) as f:
+        data = json.load(f)
+    seen = {}
+
+    def load(trace_dir, programs):
+        seen.update(programs)
+        return data
+
+    peaks = work.peaks
+    monkeypatch.setattr(layers, "load", load)
+    monkeypatch.setattr(work, "peaks", lambda kind: peaks("TPU v5 lite"))
+    cell = tiny_cell("q19_sugar")
+    r = harness.run_cell(cell, 2**31 + 5, 0.01, True, 0.0,
+                         require_tpu=False)
+    assert r["correct"] is True
+    assert {"delivery_ms_per_step", "compaction_ms_per_step",
+            "slot_chunks_per_step", "device_ms_per_step", "step_mfu",
+            "device_idle_share"} == set(r["metrics"])
+    assert r["metrics"]["slot_chunks_per_step"]["unit"] == "chunks"
+    assert r["metrics"]["slot_chunks_per_step"]["value"] > 0
+    assert 0 < r["metrics"]["compaction_ms_per_step"]["value"]
+    assert {"busy_s", "window_s"} <= set(r["device"])
+    # the warm-up kept the HLO of the program it ran, outside the window
+    assert "jit__run_scan_jit" in seen

@@ -1,8 +1,6 @@
 """Shared by the harness tests: a cell cut to a size a CPU test holds, and
 stand-ins for the entry point."""
 
-import json
-import os
 import time
 
 from bench import harness, program
@@ -12,21 +10,18 @@ program.import_program()   # the simulator under test, from ``src``
 TINY_NETWORK = {"n_neurons": 1003, "n_synapses": 60_000}
 TINY_STEPS = 120
 SEED = 2**31 + 5
-
-
-# the partitioned cell, kept out of BENCHMARK.json until it is proved on
-# four chips
-P4 = {"name": "q19_p4_bg40", "config": "flywire_q19_p4",
-      "traffic": "bg40_p4", "chips": 4}
+# The draws these tests were written against: the network from this seed,
+# the stimulated population and the lanes' streams from each call's seed.
+# At this size the control's drift flips a spike on most draws, not on
+# every one.
+TINY_DRAWS = {"seed": 181_218_205}
 
 
 def tiny_cell(name: str) -> harness.Cell:
-    if name == P4["name"]:
-        with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
-            cell = harness.cell_from(P4, json.load(f))
-    else:
-        cell = harness.load_cell(name)
-    cell.config["network"].update(TINY_NETWORK)
+    cell = harness.load_cell(name)
+    cell.config["network"].update(TINY_NETWORK, **TINY_DRAWS)
+    cell.traffic["params"].pop("seed", None)
+    cell.traffic.pop("streams_seed", None)
     cell.traffic["steps"] = TINY_STEPS
     return cell
 
